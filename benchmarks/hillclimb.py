@@ -200,4 +200,7 @@ def main(out="artifacts/hillclimb.jsonl"):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

@@ -14,22 +14,20 @@ import time
 
 
 def host_info() -> dict:
-    try:
-        import jax
+    """Host and device of the run. A JAX that cannot name its device
+    raises here: an artifact without its device is not comparable."""
+    import jax
 
-        jax_version = jax.__version__
-        backend = jax.default_backend()
-        device_count = jax.device_count()
-    except Exception:  # pragma: no cover - jax always present in this repo
-        jax_version, backend, device_count = None, None, None
+    dev = jax.devices()[0]
     return {
         "platform": platform.platform(),
         "machine": platform.machine(),
         "python": sys.version.split()[0],
         "cpu_count": os.cpu_count(),
-        "jax": jax_version,
-        "backend": backend,
-        "device_count": device_count,
+        "jax": jax.__version__,
+        "backend": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": jax.device_count(),
     }
 
 

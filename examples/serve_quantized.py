@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch import compile_cache
 from repro.quant.config import QuantConfig
 from repro.serving import Request, ServingEngine
 
@@ -24,7 +25,7 @@ def main():
                     help="SAMD weight precision (0 = bf16)")
     ap.add_argument("--backend", choices=("xla", "pallas"), default="xla",
                     help="packed-matmul backend (pallas = fused unpack "
-                         "kernel; interpret mode on CPU)")
+                         "kernel: Mosaic on a TPU, its jnp lowering on CPU)")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="0 = greedy; >0 samples in-jit (Gumbel-max)")
     ap.add_argument("--requests", type=int, default=6)
@@ -63,8 +64,10 @@ def main():
     dt = time.time() - t0
 
     total_tokens = sum(len(r.generated) for r in done)
+    dev = jax.devices()[0]
     print(f"served {len(done)} requests, {total_tokens} tokens "
-          f"in {dt:.1f}s ({total_tokens/dt:.1f} tok/s on CPU)")
+          f"in {dt:.1f}s ({total_tokens/dt:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind}, compile included)")
     print(f"  fused decode steps: {eng.stats['decode_steps']}, "
           f"batched prefills: {eng.stats['prefill_calls']}, "
           f"per-row forwards: {eng.stats['per_row_forward_calls']}")
@@ -84,4 +87,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

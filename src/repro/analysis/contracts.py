@@ -50,8 +50,10 @@ from repro.quant.config import QuantConfig
 # float32 keeps integers exact up to 2^24 (mantissa incl. implicit bit)
 F32_MANTISSA_BITS = 24
 
-# per-backend VMEM budget for one grid step's blocks + scratch. TPU cores
-# have ~16 MiB of VMEM; leave headroom for Mosaic's own double buffering.
+# per-backend VMEM budget for one grid step's blocks + scratch. Mosaic's
+# scoped VMEM limit on a v5e is 16 MiB (the figure the TPU compiler
+# reports when a kernel exceeds it); leave headroom for its own double
+# buffering.
 VMEM_LIMIT_BYTES = {
     "tpu": 12 * 2**20,
     "default": 12 * 2**20,

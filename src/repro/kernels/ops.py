@@ -157,9 +157,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            q_pos: jax.Array, *,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
-                           extra_k: jax.Array | None = None,
-                           extra_v: jax.Array | None = None,
-                           extra_pos: jax.Array | None = None,
                            block_kv_heads: int | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """Fused decode attention over the paged KV pool (no gathered copy).
@@ -175,19 +172,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     while the unrolled lowering vectorizes across slots. Pass
     ``interpret=True`` to force the Pallas interpreter (the CI
     equivalence tests do, so the kernel body itself stays covered).
-
-    ``extra_k``/``extra_v``/``extra_pos`` fold a small per-slot
-    out-of-pool KV window (the speculative draft's tick-local ring) into
-    the same online softmax, with ``q_pos`` bounding the POOL read. The
-    fold is implemented in the jnp lowering only — it is plain XLA, so
-    it compiles on every backend (TPU included) without a Pallas twin.
     """
-    if extra_k is not None:
-        return _pa.paged_decode_attention_xla(
-            q, k_pages, v_pages, page_table, q_pos,
-            k_scale=k_scale, v_scale=v_scale,
-            extra_k=extra_k, extra_v=extra_v, extra_pos=extra_pos,
-        )
     if interpret is None:
         if _default_interpret():
             return _pa.paged_decode_attention_xla(

@@ -184,9 +184,6 @@ def paged_decode_attention_xla(
     *,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
-    extra_k: jax.Array | None = None,
-    extra_v: jax.Array | None = None,
-    extra_pos: jax.Array | None = None,
     mask_value: float = DEFAULT_MASK_VALUE,
 ) -> jax.Array:
     """The SAME page-loop algorithm lowered to straight-line jnp — the
@@ -201,14 +198,6 @@ def paged_decode_attention_xla(
     truncates the table to the pow2 used-width), and unrolling deletes
     the ~100us/step while-loop overhead XLA pays on CPU. Numerics match
     the kernel: f32 accumulation, pages folded in ascending order.
-
-    ``extra_k``/``extra_v`` [B, R, Hkv, dh] (+ ``extra_pos`` [B, R],
-    -1 = unwritten) fold a small per-slot out-of-pool KV window into the
-    same online softmax AFTER the pages — the self-speculative DRAFT
-    path, whose in-flight proposals live in a tick-local bf16 ring while
-    ``q_pos`` bounds the POOL read strictly below the draft window (the
-    pool may hold a previous tick's rejected-draft KV there). Plain jnp
-    throughout, so this fold runs as ordinary XLA on every backend.
     """
     b, h, dh = q.shape
     packed = k_pages.dtype == jnp.uint32
@@ -262,27 +251,6 @@ def paged_decode_attention_xla(
     )
     for j in range(n_pp):
         carry = body(carry, pt[:, j], j * page_size)
-    if extra_k is not None:
-        m, l_sum, acc = carry
-        ek = extra_k.astype(jnp.float32)
-        ev = extra_v.astype(jnp.float32)
-        s = jnp.einsum("bhgd,brhd->bhgr", qg, ek)
-        valid = extra_pos.astype(jnp.int32) >= 0  # written ring entries
-        s = jnp.where(valid[:, None, None, :], s, mask_value)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m_new)
-        pexp = jnp.exp(s - m_new[..., None])
-        l_new = l_sum * alpha + jnp.sum(pexp, axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhgr,brhd->bhgd", pexp, ev
-        )
-        # rows with NO written ring entry keep their carry (same hazard
-        # as an invalid page: exp(mask - mask) == 1 would average noise)
-        keep = jnp.any(valid, axis=1)[:, None, None]
-        m = jnp.where(keep, m_new, m)
-        l_sum = jnp.where(keep, l_new, l_sum)
-        acc = jnp.where(keep[..., None], acc_new, acc)
-        carry = (m, l_sum, acc)
     _, l_sum, acc = carry
     out = acc / jnp.maximum(l_sum, 1e-30)[..., None]
     return out.reshape(b, h, dh).astype(q.dtype)
@@ -409,17 +377,31 @@ def paged_decode_attention(
 # nothing and emit zeros.
 
 
+def _query_positions(pos_ref, b, sq):
+    """Slot ``b``'s per-query positions as an [sq, 1, 1, 1] int32 vector
+    plus their maximum as a scalar. Mosaic loads only scalars from SMEM,
+    so the vector is assembled from ``sq`` scalar reads (sq = K+1 is a
+    handful) instead of one row load of the prefetched [B, S] table."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (sq, 1, 1, 1), 0)
+    vec = jnp.full((sq, 1, 1, 1), pos_ref[b, 0], jnp.int32)
+    top = pos_ref[b, 0]
+    for i in range(1, sq):
+        vec = jnp.where(iota == i, pos_ref[b, i], vec)
+        top = jnp.maximum(top, pos_ref[b, i])
+    return vec, top
+
+
 def _online_update_mq(
     q, k, v, base, q_pos, page_size, mask_value, m_ref, l_ref, acc_ref
 ):
     """Fold one page of K/V into the q-block online-softmax state.
 
-    q [s, hkv, g, dh] f32 (pre-scaled); q_pos [s] per-query positions
-    (-1 = fully masked row); k/v [page_size, hkv, dh] f32.
+    q [s, hkv, g, dh] f32 (pre-scaled); q_pos [s, 1, 1, 1] per-query
+    positions (-1 = fully masked row); k/v [page_size, hkv, dh] f32.
     """
     s = jnp.einsum("qhgd,phd->qhgp", q, k)  # [s, hkv, g, page_size]
     offs = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, page_size), 3)
-    s = jnp.where(offs <= q_pos[:, None, None, None], s, mask_value)
+    s = jnp.where(offs <= q_pos, s, mask_value)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     alpha = jnp.exp(m_prev - m_new)
@@ -427,7 +409,7 @@ def _online_update_mq(
     # a fully-masked query row (q_pos -1: past the slot's draft budget)
     # would see exp(mask - mask) == 1 everywhere and average page noise;
     # zeroing its mass keeps l == 0 so the epilogue emits exact zeros
-    p = jnp.where(q_pos[:, None, None, None] >= 0, p, 0.0)
+    p = jnp.where(q_pos >= 0, p, 0.0)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
     acc_ref[...] = acc_ref[...] * alpha[..., None] + jnp.einsum(
         "qhgp,phd->qhgd", p, v
@@ -452,11 +434,11 @@ def _kernel_bf16_mq(
 ):
     b, j = pl.program_id(0), pl.program_id(2)
     page = pt_ref[b, j]
-    q_pos = pos_ref[b]  # [s] per-query positions
+    q_pos, last = _query_positions(pos_ref, b, q_ref.shape[1])
     base = j * page_size
     _init_scratch(j, m_ref, l_ref, acc_ref, mask_value)
 
-    @pl.when((page >= 0) & (base <= jnp.max(q_pos)))
+    @pl.when((page >= 0) & (base <= last))
     def _accum():
         q = q_ref[0].astype(jnp.float32) * sm_scale
         k = k_ref[0].astype(jnp.float32)
@@ -487,11 +469,11 @@ def _kernel_packed_mq(
 ):
     b, j = pl.program_id(0), pl.program_id(2)
     page = pt_ref[b, j]
-    q_pos = pos_ref[b]
+    q_pos, last = _query_positions(pos_ref, b, q_ref.shape[1])
     base = j * page_size
     _init_scratch(j, m_ref, l_ref, acc_ref, mask_value)
 
-    @pl.when((page >= 0) & (base <= jnp.max(q_pos)))
+    @pl.when((page >= 0) & (base <= last))
     def _accum():
         q = q_ref[0].astype(jnp.float32) * sm_scale
         ks = ks_ref[0][..., None]

@@ -13,10 +13,11 @@ Two generations live here:
    kept where it pays on TPU: *storage*. Conv weights stay packed in HBM
    as b-bit lanes along C_in; each grid step copies a packed block to
    VMEM, unpacks in-register on the VPU, and contracts on the MXU. The
-   im2col is fused into the BlockSpec index maps — the input x is passed
-   KH times with H-axis block size 1, so block index == exact input row
-   (``oh + kh``), and the KW taps are static in-kernel column slices; NO
-   patch matrix is ever materialized. The C_in reduction is blocked with
+   im2col is fused into the BlockSpec index maps — the input, relaid as
+   rows [H, W, C], is passed KH times with a leading row block of 1, so
+   block index == exact input row (``oh + kh``), and the KW taps are
+   static sublane-offset loads of that row; NO patch matrix is ever
+   materialized. The C_in reduction is blocked with
    a float32 accumulator scratch carried across grid steps (online
    accumulation; ragged C_in zero-padded to whole blocks per the PR 2
    K-block fix), and the per-output-channel scale is applied once at the
@@ -38,7 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv import ConvPlan
 from repro.core import masks as masks_mod
-from repro.kernels.samd_matmul import unpack_codes
+from repro.kernels.samd_matmul import lane_major, unpack_codes
 from repro.quant.config import QuantConfig
 
 
@@ -148,15 +149,13 @@ def _conv2d_kernel(*refs, kh_taps, kw_taps, ow, bits, lane_width, vpw,
 
     acc = acc_ref[...]
     for kh in range(kh_taps):
-        row = x_refs[kh][:, 0, :]                        # [bc, Wp]
         for kw in range(kw_taps):
             codes = unpack_codes(
                 w_ref[kh, kw], bits, lane_width, vpw, signed
             )                                            # [bc, bn]
-            patch = row[:, kw:kw + ow]  # [bc, OW] static slice
-            acc = acc + jax.lax.dot_general(
+            patch = x_refs[kh][0, pl.ds(kw, ow), :]      # [OW, bc]
+            acc = acc + jnp.dot(
                 patch, codes.astype(patch.dtype),
-                (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
     acc_ref[...] = acc
@@ -209,11 +208,13 @@ def samd_conv2d(
 
     Grid: (OH, N-blocks, C_in-blocks) with the channel reduction innermost
     so the f32 accumulator scratch survives across reduction steps. The
-    fused im2col: x is passed KH times, each alias blocked to a single
-    input row picked by the index map ``(ci, oh + kh, 0)`` (H-axis block
-    size 1 makes the block index an exact row index — the trick that lets
-    BlockSpecs express overlapping windows), and the KW taps are static
-    column slices of that row. One weight-block unpack feeds KH*KW MXU
+    fused im2col: x is relaid once as rows [H, W, C] (channels on lanes,
+    in :func:`unpack_codes`' lane-major order) and passed KH times, each
+    alias blocked to a single input row picked by the index map
+    ``(oh + kh, 0, ci)`` (a leading-axis block of 1 makes the block index
+    an exact row index — the trick that lets BlockSpecs express
+    overlapping windows), and the KW taps are static sublane-offset
+    loads of that row. One weight-block unpack feeds KH*KW MXU
     contractions.
     """
     c_in, h, w = x.shape
@@ -225,13 +226,14 @@ def samd_conv2d(
     bn = min(block_n, n)
     bcw = min(block_cw, cw)
     x, packed, cwp = _pad_conv_operands(x, packed, padding, vpw, bcw)
-    wp = x.shape[2]
+    x = jnp.transpose(lane_major(x, vpw, bcw, axis=0), (1, 2, 0))
+    wp = x.shape[1]
     bc = bcw * vpw
     grid = (oh, pl.cdiv(n, bn), cwp // bcw)
 
     x_specs = [
-        pl.BlockSpec((bc, 1, wp), functools.partial(
-            lambda i, j, ci, kh: (ci, i + kh, 0), kh=kh))
+        pl.BlockSpec((1, wp, bc), functools.partial(
+            lambda i, j, ci, kh: (i + kh, 0, ci), kh=kh))
         for kh in range(kh_taps)
     ]
     out = pl.pallas_call(
@@ -285,6 +287,7 @@ def samd_conv2d_xla(
     ow = w + 2 * padding - kw_taps + 1
     bcw = min(block_cw, cw)
     x, packed, cwp = _pad_conv_operands(x, packed, padding, vpw, bcw)
+    x = lane_major(x, vpw, bcw, axis=0)
     bc = bcw * vpw
     acc = jnp.zeros((oh * ow, n), jnp.float32)
     for cb in range(cwp // bcw):

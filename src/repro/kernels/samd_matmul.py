@@ -21,6 +21,10 @@ serving push):
   * the per-output-channel scale is applied ONCE at the final store —
     grid steps accumulate raw integer-code products, so the unpack path
     is a pure shift/mask chain with no float multiply per lane;
+  * codes unpack LANE-MAJOR (all words' lane 0, then lane 1, ...) and the
+    activation's reduction axis is permuted to match before launch
+    (:func:`lane_major`): Mosaic cannot interleave the lanes back into
+    word order in-register;
   * signed lanes sign-extend with a two-op mask/subtract; ``signed=False``
     lanes (codes that fit the lane headroom with no sign bit) skip the
     correction entirely — the fast path.
@@ -56,27 +60,42 @@ from repro.quant.config import QuantConfig
 
 def unpack_codes(words, bits: int, lane_width: int, vpw: int,
                  signed: bool = True):
-    """uint32 [bk, bn] -> int32 codes [bk * vpw, bn] (VPU shift/mask ops).
+    """uint32 [bk, bn] -> int32 codes [vpw * bk, bn] (VPU shift/mask ops).
 
-    All lanes are extracted by one broadcasted shift over a [vpw, 1, 1]
-    shift vector — the trace has a single shift/mask/select chain whose
-    size does not depend on the lane count. Signed lanes append a two-op
-    sign correction (extract the sign bit, subtract ``sign << bits``);
-    unsigned lanes skip it — their codes already fit the lane headroom.
-    The correction is applied HERE, inside the kernels, so no caller ever
-    has to remember the wide-lane fixup by hand (the PR 2 footgun).
+    Codes come out LANE-MAJOR: row ``i * bk + w`` holds lane ``i`` of
+    word ``w``, i.e. reduction index ``w * vpw + i`` of the block. Each
+    lane is one shift/mask over the whole [bk, bn] word tile and the
+    lanes stack along the sublane axis, so Mosaic never has to interleave
+    rows (a [vpw, bk, bn] -> [bk * vpw, bn] transpose-and-merge, which it
+    refuses). The matching activation columns come from
+    :func:`lane_major`. Signed lanes append a two-op sign correction
+    (extract the sign bit, subtract ``sign << bits``); unsigned lanes
+    skip it — their codes already fit the lane headroom. The correction
+    is applied HERE, inside the kernels, so no caller ever has to
+    remember the wide-lane fixup by hand.
     """
-    bk, bn = words.shape
     vmask = jnp.uint32((1 << bits) - 1)
-    shifts = (
-        jnp.arange(vpw, dtype=jnp.uint32) * jnp.uint32(lane_width)
-    ).reshape(vpw, 1, 1)
-    v = (words[None] >> shifts) & vmask       # [vpw, bk, bn]
-    v = jnp.moveaxis(v, 0, 1).reshape(bk * vpw, bn).astype(jnp.int32)
+    v = jnp.concatenate(
+        [(words >> jnp.uint32(i * lane_width)) & vmask for i in range(vpw)],
+        axis=0,
+    ).astype(jnp.int32)
     if signed:
         sign = (v >> (bits - 1)) & 1
         v = v - (sign << bits)
     return v
+
+
+def lane_major(x, vpw: int, bkw: int, axis: int):
+    """Permute ``x``'s reduction ``axis`` (whole blocks of ``bkw`` packed
+    words, ``bkw * vpw`` values each) into the order
+    :func:`unpack_codes` emits codes in: within every block, position
+    ``i * bkw + w`` takes reduction index ``w * vpw + i``. A pure
+    relabelling of the reduction, so the contraction is unchanged."""
+    n = x.shape[axis] // (bkw * vpw)
+    lead, tail = x.shape[:axis], x.shape[axis + 1:]
+    x = x.reshape(lead + (n, bkw, vpw) + tail)
+    x = jnp.swapaxes(x, axis + 1, axis + 2)
+    return x.reshape(lead + (n * vpw * bkw,) + tail)
 
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits, lane_width, vpw,
@@ -151,6 +170,7 @@ def samd_matmul(
     bn = min(block_n, n)
     bkw = min(block_kw, kw)
     x, packed, kw = _pad_packed_operands(x, packed, k, vpw, bkw)
+    x = lane_major(x, vpw, bkw, axis=1)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(kw, bkw))
 
     out = pl.pallas_call(
@@ -203,6 +223,7 @@ def samd_matmul_xla(
     assert kw * vpw >= k, (kw, vpw, k)
     bkw = min(block_kw, kw)
     x, packed, kw = _pad_packed_operands(x, packed, k, vpw, bkw)
+    x = lane_major(x, vpw, bkw, axis=1)
     acc = jnp.zeros((m, n), jnp.float32)
     for kb in range(kw // bkw):
         words = packed[kb * bkw:(kb + 1) * bkw]

@@ -340,12 +340,13 @@ def speculative_accept(logits: jax.Array, draft_tok: jax.Array,
 
 
 def make_draft_step(cfg: ArchConfig, run: RunConfig, page_size: int,
-                    k_spec: int, paged_attn: str = "fused"):
+                    k_spec: int):
     """Draft half of the speculative tick: ``k_spec`` unrolled low-bit
     autoregressive steps per slot. Each step's K/V lands in a tick-local
     bf16 ring (``init_cache(cfg, B, k_spec)`` built in-trace — never the
     pool), while pool history is read read-only STRICTLY BELOW the
-    window base. Returns (draft_tok [B, K], draft_logits [B, K, V])."""
+    window base, through the dense page gather. Returns (draft_tok
+    [B, K], draft_logits [B, K, V])."""
     max_len = run.shape.seq_len
     assert k_spec >= 1, k_spec
 
@@ -362,7 +363,6 @@ def make_draft_step(cfg: ArchConfig, run: RunConfig, page_size: int,
                 draft_params, cur, cfg,
                 positions=(pos + j)[:, None], cache=ring, cache_index=j,
                 page_table=page_table, page_size=page_size,
-                paged_attn=paged_attn,
                 pool_cache=cache, pool_bound=pool_bound,
             )
             lgj = lg[:, -1]
@@ -422,7 +422,7 @@ def make_speculative_step(cfg: ArchConfig, run: RunConfig, page_size: int,
     cursor resumes at the first unaccepted position).
     """
     assert paged_attn in ("fused", "gather"), paged_attn
-    draft = make_draft_step(cfg, run, page_size, k_spec, paged_attn)
+    draft = make_draft_step(cfg, run, page_size, k_spec)
     verify = make_speculative_verify_step(cfg, run, page_size, k_spec,
                                           paged_attn)
 

@@ -377,27 +377,19 @@ def attention_block(
         cpos = _cache_write(
             kv_cache["pos"], positions.astype(jnp.int32), cache_index, s)
         new_cache = {"k": ck, "v": cv, "pos": cpos}
-        if paged_attn == "fused" and s == 1:
-            # pool page loop + one ring fold, single online softmax (the
-            # jnp lowering — plain XLA on every backend, see kernels.ops)
-            att = kernel_ops.paged_decode_attention(
-                q[:, 0], pool_kv["k"], pool_kv["v"], page_table,
-                pool_bound,
-                k_scale=pool_kv.get("k_scale"),
-                v_scale=pool_kv.get("v_scale"),
-                extra_k=ck, extra_v=cv, extra_pos=cpos,
-            )[:, None]
-        else:
-            k_pos_pool = _paged_key_positions(page_table, page_size)
-            k_pos_pool = jnp.where(
-                k_pos_pool <= pool_bound[:, None], k_pos_pool, -1)
-            pool_k, pool_v = _gathered_pool_kv(pool_kv, page_table,
-                                               page_size, q.dtype)
-            k_full = jnp.concatenate([pool_k, ck.astype(q.dtype)], axis=1)
-            v_full = jnp.concatenate([pool_v, cv.astype(q.dtype)], axis=1)
-            k_pos = jnp.concatenate([k_pos_pool, cpos], axis=1)
-            att = attention(q, k_full, v_full, positions, k_pos,
-                            chunk=chunk)
+        # pool pages <= pool_bound plus the ring, one dense softmax over
+        # the per-row page gather, on every backend: an unrolled jnp page
+        # loop here (one per layer and draft step) took the TPU compiler
+        # over 20 minutes for a 24-layer model at 64 pages per slot
+        k_pos_pool = _paged_key_positions(page_table, page_size)
+        k_pos_pool = jnp.where(
+            k_pos_pool <= pool_bound[:, None], k_pos_pool, -1)
+        pool_k, pool_v = _gathered_pool_kv(pool_kv, page_table,
+                                           page_size, q.dtype)
+        k_full = jnp.concatenate([pool_k, ck.astype(q.dtype)], axis=1)
+        v_full = jnp.concatenate([pool_v, cv.astype(q.dtype)], axis=1)
+        k_pos = jnp.concatenate([k_pos_pool, cpos], axis=1)
+        att = attention(q, k_full, v_full, positions, k_pos, chunk=chunk)
     elif kv_cache is not None:
         # int8 ring rows, or SAMD-packed uint32 page pools (kv_bits=8)
         quantized_kv = kv_cache["k"].dtype in (jnp.int8, jnp.uint32)

@@ -393,7 +393,10 @@ class ServingEngine:
             # to trade memory for preemption under pressure.
             num_pages = max_batch * self.pages_per_slot
         self.num_pages = num_pages
-        template = build_template(cfg)
+        # per-layer parameter list whatever ``cfg.scan_layers`` says: the
+        # paged pools, the COW page copy and the speculative draft all use
+        # the unrolled {'layers': [...]} cache layout
+        template = build_template(cfg, stacked=False)
         if params is None:
             params = init_from_spec(template, jax.random.PRNGKey(seed))
         raw_params = params
@@ -1205,16 +1208,8 @@ class ServingEngine:
         if self.decode_mode == "ragged" and self.speculative:
             return self._step_speculative()
         if self.decode_mode == "ragged":
-            args = [
-                self.params,
-                jnp.asarray(self.slot_next[:, None]), self.cache,
-                jnp.asarray(self.slot_pos), jnp.asarray(self.active),
-            ]
-            if self.kv_mode == "paged":
-                args.append(jnp.asarray(self._active_table()))
             next_ids, self.cache = self._ragged_step(
-                *args, self._next_key(), jnp.float32(self.temperature)
-            )
+                *self._decode_args(self._next_key()))
             self.stats["decode_steps"] += 1
             next_ids = np.asarray(next_ids)  # the ONE host sync per tick
         else:
@@ -1222,6 +1217,24 @@ class ServingEngine:
         for i in np.nonzero(self.active)[0]:
             self._advance_slot(int(i), int(next_ids[i]))
         return True
+
+    def _decode_args(self, key) -> list:
+        """Arguments of the plain (non-speculative) ragged decode step
+        for the current slot state."""
+        args = [
+            self.params,
+            jnp.asarray(self.slot_next[:, None]), self.cache,
+            jnp.asarray(self.slot_pos), jnp.asarray(self.active),
+        ]
+        if self.kv_mode == "paged":
+            args.append(jnp.asarray(self._active_table()))
+        return args + [key, jnp.float32(self.temperature)]
+
+    def decode_program(self):
+        """The plain decode tick's program, compiled for the current slot
+        state (a ``jax.stages.Compiled``: ``as_text()`` shows which
+        kernels the tick runs). Changes no engine state."""
+        return self._ragged_step.lower(*self._decode_args(self._key)).compile()
 
     def _step_speculative(self) -> bool:
         """One speculative tick: grant lookahead pages, run the fused

@@ -3,31 +3,23 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:
-    import hypothesis
+import hypothesis  # noqa: E402
 
-    # Derandomized, no-deadline profile for CI: property tests must not
-    # flake because a slow shared runner blew hypothesis's per-example
-    # deadline, and a red CI run must be reproducible locally (derandomize
-    # fixes the example sequence). Selected whenever CI is set (GitHub
-    # Actions exports CI=true); HYPOTHESIS_PROFILE overrides.
-    hypothesis.settings.register_profile(
-        "ci",
-        deadline=None,
-        derandomize=True,
-        max_examples=50,
+# Derandomized, no-deadline profile for CI: property tests must not
+# flake because a slow shared runner blew hypothesis's per-example
+# deadline, and a red CI run must be reproducible locally (derandomize
+# fixes the example sequence). Selected whenever CI is set (GitHub
+# Actions exports CI=true); HYPOTHESIS_PROFILE overrides.
+hypothesis.settings.register_profile(
+    "ci",
+    deadline=None,
+    derandomize=True,
+    max_examples=50,
+)
+if os.environ.get("CI"):
+    hypothesis.settings.load_profile(
+        os.environ.get("HYPOTHESIS_PROFILE", "ci")
     )
-    if os.environ.get("CI"):
-        hypothesis.settings.load_profile(
-            os.environ.get("HYPOTHESIS_PROFILE", "ci")
-        )
-except ModuleNotFoundError:
-    # container images without hypothesis: run property tests as a
-    # deterministic fixed-seed sweep instead of failing collection
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
 
 
 # ---------------------------------------------------------------------------
@@ -40,7 +32,7 @@ except ModuleNotFoundError:
 # yet another engine-construction variant. Imports stay inside methods:
 # collection must not pay for (or depend on) jax.
 
-import numpy as np  # noqa: E402  (after the hypothesis stub install)
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 

@@ -4,6 +4,7 @@ Multi-device cases run in a subprocess with fake CPU devices, because the
 main test process must keep the default single-device view (per the
 project's dry-run isolation rule).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -126,10 +127,11 @@ MULTIDEV_SNIPPET = textwrap.dedent("""
     from repro.configs.base import ShapeConfig
     from repro.distributed.sharding import param_pspecs, named
     from repro.launch import steps as steps_mod
+    from repro.launch.mesh import make_test_mesh
     from repro.models import build_template, init_from_spec
     from repro.optim.adamw import adamw_init
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh(2, 4)
     cfg = smoke_config("qwen1.5-0.5b").scaled(d_model=64, d_ff=128, vocab=256,
                                               n_heads=4, n_kv_heads=4,
                                               head_dim=16)
@@ -167,7 +169,7 @@ def test_sharded_train_step_matches_unsharded():
     r = subprocess.run(
         [sys.executable, "-c", MULTIDEV_SNIPPET],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
+        env={**os.environ, "PYTHONPATH": "src"},
         cwd=__file__.rsplit("/", 2)[0],
     )
     assert "MULTIDEV_OK" in r.stdout, r.stdout + r.stderr

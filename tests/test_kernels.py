@@ -234,42 +234,6 @@ def test_paged_verify_attention_matches_per_token_decode(packed, lowering):
                 assert np.all(got[i, j] == 0.0), (i, j)
 
 
-def test_paged_decode_attention_extra_ring_fold():
-    """The draft-path fold: pool pages truncated to <= q_pos PLUS a small
-    out-of-pool ring must equal the gather oracle over the concatenated
-    key set (ring entries with pos -1 are unwritten and masked)."""
-    P, ps, hkv, dh, n_pp, r, b = 8, 4, 2, 16, 3, 3, 2
-    rng = np.random.default_rng(23)
-    kp, vp, _, _ = _paged_pools(rng, P, ps, hkv, dh, packed=False)
-    q = jnp.asarray(rng.normal(size=(b, hkv, dh)), jnp.bfloat16)
-    pt = jnp.asarray([[0, 3, 5], [6, -1, -1]], jnp.int32)
-    bound = jnp.asarray([8, 2], jnp.int32)  # pool read cap per row
-    ek = jnp.asarray(rng.normal(size=(b, r, hkv, dh)), jnp.bfloat16)
-    ev = jnp.asarray(rng.normal(size=(b, r, hkv, dh)), jnp.bfloat16)
-    epos = jnp.asarray([[9, 10, -1], [3, -1, -1]], jnp.int32)
-    got = np.asarray(ops.paged_decode_attention(
-        q, kp, vp, pt, bound, extra_k=ek, extra_v=ev, extra_pos=epos,
-    ), np.float32)
-    # oracle: dense gather of pool (masked beyond bound) + ring concat
-    from repro.models.layers import (
-        _paged_gather, _paged_key_positions, attention,
-    )
-
-    k_pos = _paged_key_positions(pt, ps)
-    k_pos = jnp.where(k_pos <= bound[:, None], k_pos, -1)
-    kg = _paged_gather(kp, pt, ps).astype(jnp.bfloat16)
-    vg = _paged_gather(vp, pt, ps).astype(jnp.bfloat16)
-    k_full = jnp.concatenate([kg, ek], axis=1)
-    v_full = jnp.concatenate([vg, ev], axis=1)
-    kp_full = jnp.concatenate([k_pos, epos], axis=1)
-    q_pos = jnp.asarray([[10], [3]], jnp.int32)  # newest ring entry
-    want = np.asarray(
-        attention(q[:, None], k_full, v_full, q_pos, kp_full)[:, 0],
-        np.float32,
-    )
-    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
-
-
 @pytest.mark.parametrize("bits", [2, 3, 4])
 @pytest.mark.parametrize("signed", [False, True])
 @pytest.mark.parametrize("n", [50, 333, 1024])

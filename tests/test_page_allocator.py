@@ -11,10 +11,6 @@ invariants must hold:
   * no page is simultaneously free and mapped (held);
   * no double-grant: every page granted by alloc/claim_reserved was free
     and is returned at refcount exactly 1.
-
-Strategies stay within the subset the tests/_hypothesis_stub fallback
-implements (``st.integers`` + a seed-driven numpy rng), so the test runs
-with or without the real hypothesis package.
 """
 import numpy as np
 from hypothesis import given, settings

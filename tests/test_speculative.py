@@ -113,30 +113,53 @@ def test_quantized_draft_still_token_identical(serving):
     assert eng.stats["draft_proposed"] >= eng.stats["draft_accepted"] >= 0
 
 
+def _successor_model(serving, vocab=64):
+    """A model whose greedy continuation of token t is t + 1, built
+    directly rather than searched for among random inits (whose greedy
+    runs, and so the existence of a usable eos, depend on the PRNG).
+
+    Embeddings are the identity, every block's output projection is zero
+    (the residual stream stays ``embed[t]``), and the untied LM head maps
+    row t to column t + 1. Attention still runs, reads and writes the
+    paged KV, and the low-bit draft sees the same weights packed."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import build_template, init_from_spec
+
+    cfg = serving.cfg(vocab=vocab, d_model=vocab, tie_embeddings=False)
+    params = init_from_spec(build_template(cfg), jax.random.PRNGKey(0))
+    eye = jnp.eye(vocab, dtype=jnp.bfloat16)
+    params["embed"] = eye
+    params["lm_head"] = jnp.roll(eye, 1, axis=1)
+    for blk in params["blocks"]:
+        blk["attn"]["wo"] = jnp.zeros_like(blk["attn"]["wo"])
+        blk["mlp"]["wd"] = jnp.zeros_like(blk["mlp"]["wd"])
+    return cfg, params
+
+
 def test_spec_respects_eos_mid_accepted_run(serving):
     """An eos landing inside an accepted run must stop consumption there
     (tokens past it are discarded with their KV)."""
-    # find a prompt whose greedy run has a token FIRST appearing mid-run
-    # (greedy on a tiny random model often cycles, so search a few)
-    for pseed in range(8):
-        prompt = (np.arange(9) * 5 + 2 + 31 * pseed) % 256
-        ref_eng = serving.engine()
-        ref_eng.submit(Request(rid=0, prompt=prompt.copy(), max_tokens=8))
-        ref = ref_eng.run_to_completion()[0].generated
-        idx = next(
-            (i for i in range(2, len(ref)) if ref[i] not in ref[:i]), None
-        )
-        if idx is not None:
-            break
-    assert idx is not None, "no prompt with a mid-run first occurrence"
-    eos = ref[idx]
+    cfg, params = _successor_model(serving)
+    prompt = np.array([3, 1, 4, 1, 5], np.int32)
+    ref_eng = serving.engine(cfg=cfg, params=params)
+    ref_eng.submit(Request(rid=0, prompt=prompt.copy(), max_tokens=8))
+    ref = ref_eng.run_to_completion()[0].generated
+    assert ref == list(range(6, 14)), ref  # every token first occurs once
+    # prefill emits ref[0]; the first speculative tick accepts every
+    # draft and emits ref[1:k+2], so ref[2] sits strictly inside that
+    # tick's accepted run for k=2 and k=4
+    idx = 2
     for k in (2, 4):
-        eng = serving.engine(speculative=k)
+        eng = serving.engine(cfg=cfg, params=params, speculative=k)
         eng.submit(
-            Request(rid=0, prompt=prompt.copy(), max_tokens=8, eos_id=eos)
+            Request(rid=0, prompt=prompt.copy(), max_tokens=8,
+                    eos_id=ref[idx])
         )
         got = eng.run_to_completion()[0].generated
         assert got == ref[: idx + 1], (k, got, ref)
+        assert eng.stats["spec_ticks"] == 1, eng.stats
 
 
 # ---------------------------------------------------------------------------
