@@ -1,4 +1,4 @@
-"""Benchmark entry point — one table per paper figure + roofline summary.
+"""Benchmark entry point — one table per paper figure.
 
 Prints ``name,us_per_call,derived`` CSV rows:
   * vggb/<layer>/<variant>      — paper Figs. 15/16 analogue (this host's
@@ -17,8 +17,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
                                   mixed arrival times: value = tokens/s,
                                   derived = speedup vs the per-row
                                   fallback baseline (bench_serving.py).
-  * roofline/<summary>          — dry-run cell counts by bound (if the
-                                  artifact exists).
 
 ``--json`` additionally writes machine-readable BENCH_<table>.json files
 (per-table rows + host info; see jsonio.py) so the perf trajectory is
@@ -80,11 +78,9 @@ def main() -> None:
     ap.add_argument("--out-dir", default=".")
     ap.add_argument("--no-serving", action="store_true",
                     help="skip the serving throughput table")
-    ap.add_argument("--roofline-artifact",
-                    default="artifacts/dryrun_baseline.jsonl")
     args = ap.parse_args()
 
-    from benchmarks import bench_serving, bench_vggb, roofline
+    from benchmarks import bench_serving, bench_vggb
 
     all_rows: list[tuple[str, float, float]] = []
 
@@ -124,15 +120,6 @@ def main() -> None:
         csv_rows, serving_json_rows = bench_serving.run(quick=not args.full)
         for name, tps, speedup in csv_rows:
             emit(name, tps, speedup, fmt="{:.2f},{:.2f}")
-
-    rows = roofline.load(args.roofline_artifact)
-    if rows:
-        s = roofline.summarize(rows)
-        emit("roofline/cells_ok", s["ok"], 0)
-        emit("roofline/cells_skipped", s["skipped"], 0)
-        emit("roofline/cells_failed", s["failed"], 0)
-        for bound, cnt in s["by_bound"].items():
-            emit(f"roofline/bound_{bound}", cnt, 0)
 
     if args.json:
         from benchmarks.jsonio import write_bench_json

@@ -358,6 +358,8 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=interpret,
+        # the trace names the kernel's op by this name
+        name="paged_decode_attention",
     )(*operands)
     return out.reshape(b, h, dh)
 
@@ -660,5 +662,7 @@ def paged_verify_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, sq, hkv, g, dh), q.dtype),
         interpret=interpret,
+        # the trace names the kernel's op by this name
+        name="paged_verify_attention",
     )(*operands)
     return out.reshape(b, sq, h, dh)

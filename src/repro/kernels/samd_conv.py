@@ -252,6 +252,8 @@ def samd_conv2d(
         out_shape=jax.ShapeDtypeStruct((oh, ow, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((ow, bn), jnp.float32)],
         interpret=interpret,
+        # the trace names the kernel's op by this name
+        name="samd_conv2d",
     )(*([x] * kh_taps), packed, scale)
     return out
 

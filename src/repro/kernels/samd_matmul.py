@@ -188,6 +188,8 @@ def samd_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        # the trace names the kernel's op by this name
+        name="samd_matmul",
     )(x, packed, scale)
     return out
 

@@ -166,9 +166,10 @@ def sample_tokens(logits: jax.Array, key: jax.Array,
         return jnp.argmax(lf / jnp.maximum(temperature, 1e-6) + g, axis=-1)
 
     # lax.cond: the greedy branch never pays for the [B, vocab] Gumbel draw
-    return jax.lax.cond(temperature > 0, sample, greedy, key).astype(
-        jnp.int32
-    )
+    with jax.named_scope("sample"):
+        return jax.lax.cond(temperature > 0, sample, greedy, key).astype(
+            jnp.int32
+        )
 
 
 def make_ragged_serve_step(cfg: ArchConfig, run: RunConfig):

@@ -248,12 +248,15 @@ def _paged_write(pool: jax.Array, val: jax.Array, page_table: jax.Array,
     slots named by (page_table, positions) — the paged generalization of
     the ragged ``_cache_write``. Invalid positions are dropped."""
     p = pool.shape[0]
-    flat = pool.reshape((p * page_size,) + pool.shape[2:])
-    idx = _paged_flat_index(page_table, positions, page_size, p * page_size)
-    out = flat.at[idx.reshape(-1)].set(
-        val.astype(pool.dtype).reshape((-1,) + val.shape[2:]), mode="drop"
-    )
-    return out.reshape(pool.shape)
+    with jax.named_scope("kv_write"):
+        flat = pool.reshape((p * page_size,) + pool.shape[2:])
+        idx = _paged_flat_index(page_table, positions, page_size,
+                                p * page_size)
+        out = flat.at[idx.reshape(-1)].set(
+            val.astype(pool.dtype).reshape((-1,) + val.shape[2:]),
+            mode="drop",
+        )
+        return out.reshape(pool.shape)
 
 
 def _paged_gather(pool: jax.Array, page_table: jax.Array,

@@ -509,7 +509,8 @@ def forward(
     # gather THEN cast: the backward scatter-add into the embedding table
     # accumulates in f32 (casting first would accumulate in bf16, whose
     # rounding depends on XLA fusion — remat vs no-remat would disagree)
-    x = params["embed"][tokens].astype(jnp.bfloat16)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.bfloat16)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
         s = x.shape[1]
@@ -531,38 +532,30 @@ def forward(
             params, x, positions, cfg, remat, cache, cache_index,
             page_table, page_size, paged_attn,
         )
-        x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = L.apply_linear(
-                jnp.transpose(params["embed"]).astype(x.dtype), x
-            )
-        else:
-            logits = L.apply_linear(params["lm_head"], x)
         new_cache = (
             {"layers_stacked": new_stacked} if cache is not None else None
         )
-        return logits, new_cache, aux_total
+        return _lm_head(params, x, cfg), new_cache, aux_total
+
+    def attn(p, x, kv_c, pool_c):
+        with jax.named_scope("attn"):
+            return L.attention_block(
+                p["attn"], x, positions, cfg,
+                kv_cache=kv_c, cache_index=cache_index,
+                page_table=page_table, page_size=page_size,
+                paged_attn=paged_attn, pool_kv=pool_c,
+                pool_bound=pool_bound, chunk=cfg.attn_chunk,
+            )
 
     def dense_block(p, x, kv_c, pool_c):
-        delta, new_kv = L.attention_block(
-            p["attn"], x, positions, cfg,
-            kv_cache=kv_c, cache_index=cache_index,
-            page_table=page_table, page_size=page_size,
-            paged_attn=paged_attn, pool_kv=pool_c, pool_bound=pool_bound,
-            chunk=cfg.attn_chunk,
-        )
+        delta, new_kv = attn(p, x, kv_c, pool_c)
         x = x + delta
-        x = x + L.mlp_block(p["mlp"], x, cfg)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp_block(p["mlp"], x, cfg)
         return x, new_kv
 
     def moe_layer(p, x, kv_c, pool_c):
-        delta, new_kv = L.attention_block(
-            p["attn"], x, positions, cfg,
-            kv_cache=kv_c, cache_index=cache_index,
-            page_table=page_table, page_size=page_size,
-            paged_attn=paged_attn, pool_kv=pool_c, pool_bound=pool_bound,
-            chunk=cfg.attn_chunk,
-        )
+        delta, new_kv = attn(p, x, kv_c, pool_c)
         x = x + delta
         mo, aux = L.moe_block(p["moe"], x, cfg,
                               group_tokens=cfg.moe_group_tokens)
@@ -614,13 +607,16 @@ def forward(
             new_layers.append(new_state)
         x = _constrain(x)  # optional seq-parallel activation sharding
 
-    x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = L.apply_linear(
-            jnp.transpose(params["embed"]).astype(x.dtype), x
-        )
-    else:
-        logits = L.apply_linear(params["lm_head"], x)
-
     new_cache = {"layers": new_layers} if cache is not None else None
-    return logits, new_cache, aux_total
+    return _lm_head(params, x, cfg), new_cache, aux_total
+
+
+def _lm_head(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """Final norm, then logits against the (tied) output embedding."""
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            return L.apply_linear(
+                jnp.transpose(params["embed"]).astype(x.dtype), x
+            )
+        return L.apply_linear(params["lm_head"], x)

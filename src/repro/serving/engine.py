@@ -116,9 +116,13 @@ automatic fallback for recurrent families and ``decode_mode="per_row"``);
 ``decode_mode="per_row"`` keeps the old per-row reference path (slow, one
 ``forward`` per slot per tick) for equivalence tests and as the benchmark
 baseline. ``ServingEngine.stats`` counts compiled-step, per-row-forward,
-page-grant, prefix-hit, COW-fork, preemption and OOP-retire events plus
-the peak page-pool occupancy, so tests can assert the hot path stays
-fused and pool pressure (and the sharing win) is visible.
+page-grant, prefix-hit, COW-fork, preemption and OOP-retire events, the
+prefill and decode work done against the work computed (prompt tokens
+written vs positions, active rows vs rows), plus the peak page-pool
+occupancy, so tests can assert the hot path stays fused and pool
+pressure (and the sharing win) is visible. ``serve.*`` trace spans
+(``serving/tracing.py``) mark each phase of a tick while the JAX
+profiler records.
 """
 from __future__ import annotations
 
@@ -138,6 +142,7 @@ from repro.models import (
     init_from_spec, quantize_params,
 )
 from repro.quant.config import QuantConfig
+from repro.serving.tracing import span
 
 
 @dataclasses.dataclass
@@ -163,11 +168,16 @@ class Request:
     # by default; None until the event happens). The serving front door's
     # metrics layer derives TTFT / TPOT / e2e latency from these:
     #   t_submit      stamped by ``submit`` (arrival at the engine)
-    #   t_admit       first successful admission (prefill handoff);
-    #                 survives preemption-resume unchanged
+    #   t_prefill     just before the first prefill program that holds
+    #                 the request is launched: the end of its wait
+    #   t_admit       first successful admission, stamped after that
+    #                 prefill has run and its tokens were read back
     #   t_first_token first generated token (prefill's handoff sample)
     #   t_retire      retirement, any outcome (done/truncated/rejected)
+    # t_prefill and t_admit survive preemption-resume unchanged, so
+    # t_submit <= t_prefill <= t_admit
     t_submit: Optional[float] = None
+    t_prefill: Optional[float] = None
     t_admit: Optional[float] = None
     t_first_token: Optional[float] = None
     t_retire: Optional[float] = None
@@ -519,6 +529,10 @@ class ServingEngine:
         self.stats = {
             "decode_steps": 0,          # fused ragged decode invocations
             "prefill_calls": 0,         # batched/fused prefill invocations
+            "prefill_tokens": 0,        # prompt tokens prefill wrote
+            "prefill_positions": 0,     # positions the prefill computed
+            "decode_rows": 0,           # active rows over decode steps
+            "decode_slots": 0,          # rows the decode steps computed
             "per_row_prefill_calls": 0,
             "per_row_forward_calls": 0,  # reference decode path only
             "page_grants": 0,           # incremental mid-decode page allocs
@@ -799,59 +813,60 @@ class ServingEngine:
         return "ok", start
 
     def _admit(self):
-        while self.queue:
-            free = [i for i, r in enumerate(self.slots) if r is None]
-            if not free:
-                return
-            batch: list[Request] = []
-            batch_slots: list[int] = []
-            batch_effs: list[np.ndarray] = []
-            batch_starts: list[int] = []
-            pending_ready: list[int] = []  # fork-eligible after prefill
-            stalled = False
-            while self.queue and len(batch) < len(free):
-                req = self.queue.popleft()
-                eff = self._eff_prompt(req)
-                if len(eff) >= self.max_len:
-                    # bugfix: this used to trip an assert inside prefill and
-                    # kill the engine mid-tick, losing every in-flight
-                    # request
-                    self._reject(
-                        req,
-                        f"prompt length {len(eff)} >= max_len "
-                        f"{self.max_len}",
-                    )
-                    continue
-                slot = free[len(batch)]
-                start = 0
-                if self.kv_mode == "paged":
-                    status, start = self._paged_bind(slot, req, eff,
-                                                     pending_ready)
-                    if status == "wait":
-                        self.queue.appendleft(req)
-                        stalled = True
-                        break
-                    if status == "reject":
+        with span("serve.admit"):
+            while self.queue:
+                free = [i for i, r in enumerate(self.slots) if r is None]
+                if not free:
+                    return
+                batch: list[Request] = []
+                batch_slots: list[int] = []
+                batch_effs: list[np.ndarray] = []
+                batch_starts: list[int] = []
+                pending_ready: list[int] = []  # fork-eligible after prefill
+                stalled = False
+                while self.queue and len(batch) < len(free):
+                    req = self.queue.popleft()
+                    eff = self._eff_prompt(req)
+                    if len(eff) >= self.max_len:
+                        # bugfix: this used to trip an assert inside
+                        # prefill and kill the engine mid-tick, losing
+                        # every in-flight request
+                        self._reject(
+                            req,
+                            f"prompt length {len(eff)} >= max_len "
+                            f"{self.max_len}",
+                        )
                         continue
-                batch.append(req)
-                batch_slots.append(slot)
-                batch_effs.append(eff)
-                batch_starts.append(start)
-            if not batch:
-                return
-            if self._batched_prefill:
-                self._prefill_batch(batch_slots, batch, batch_effs,
-                                    batch_starts)
-                # freshly-written full blocks may now serve as COW fork
-                # sources (their KV is on device) — unless the batch
-                # already freed them again (done-at-admit requests)
-                self._prefix_ready.update(
-                    p for p in pending_ready if p in self._page_key)
-            else:
-                for slot, req in zip(batch_slots, batch):
-                    self._prefill_one(slot, req)
-            if stalled:
-                return
+                    slot = free[len(batch)]
+                    start = 0
+                    if self.kv_mode == "paged":
+                        status, start = self._paged_bind(slot, req, eff,
+                                                         pending_ready)
+                        if status == "wait":
+                            self.queue.appendleft(req)
+                            stalled = True
+                            break
+                        if status == "reject":
+                            continue
+                    batch.append(req)
+                    batch_slots.append(slot)
+                    batch_effs.append(eff)
+                    batch_starts.append(start)
+                if not batch:
+                    return
+                if self._batched_prefill:
+                    self._prefill_batch(batch_slots, batch, batch_effs,
+                                        batch_starts)
+                    # freshly-written full blocks may now serve as COW fork
+                    # sources (their KV is on device) — unless the batch
+                    # already freed them again (done-at-admit requests)
+                    self._prefix_ready.update(
+                        p for p in pending_ready if p in self._page_key)
+                else:
+                    for slot, req in zip(batch_slots, batch):
+                        self._prefill_one(slot, req)
+                if stalled:
+                    return
 
     def _prefill_batch(self, slots: list[int], reqs: list[Request],
                        effs: list[np.ndarray], starts: list[int]):
@@ -871,45 +886,56 @@ class ServingEngine:
         ), "admission rejects over-long prompts"
         lb = _bucket_len(max(lens), self.max_len)
         nb = self.max_batch
-        tokens = np.zeros((nb, lb), np.int32)
-        lens_a = np.zeros(nb, np.int32)
-        starts_a = np.zeros(nb, np.int32)
-        valid = np.zeros(nb, bool)
-        for row, (eff, st) in enumerate(zip(effs, starts)):
-            tokens[row, :lens[row]] = eff[st:]
-            lens_a[row] = lens[row]
-            starts_a[row] = st
-            valid[row] = True
-        if self.kv_mode == "paged":
-            # rows write through their target slot's page table, truncated
-            # to the admitted batch's used page columns (pow2-bucketed like
-            # the decode table — prefill attention work then scales with
-            # the prompts' pages, not pages_per_slot). Width covers the
-            # SHARED prefix blocks too: suffix queries attend to them.
-            max_blocks = max(
-                -(-len(e) // self.page_size) for e in effs)
-            width = self._pow2_width(max_blocks)
-            route = np.full((nb, width), -1, np.int32)
-            for row, slot in enumerate(slots):
-                route[row] = self.page_table[slot, :width]
-            tok0, self.cache = self._prefill_step(
-                self.params, jnp.asarray(tokens), jnp.asarray(lens_a),
-                jnp.asarray(starts_a), jnp.asarray(route),
-                jnp.asarray(valid), self.cache,
-                self._next_key(), jnp.float32(self.temperature),
-            )
-        else:
-            # rows are blended into their target slot's ring row in-jit
-            route = np.zeros(nb, np.int32)
-            for row, slot in enumerate(slots):
-                route[row] = slot
-            tok0, self.cache = self._prefill_step(
-                self.params, jnp.asarray(tokens), jnp.asarray(lens_a),
-                jnp.asarray(route), jnp.asarray(valid), self.cache,
-                self._next_key(), jnp.float32(self.temperature),
-            )
-        self.stats["prefill_calls"] += 1
-        tok0 = np.asarray(tok0)
+        # rows write through their target slot's page table, truncated to
+        # the admitted batch's used page columns (pow2-bucketed like the
+        # decode table — prefill attention work then scales with the
+        # prompts' pages, not pages_per_slot). Width covers the SHARED
+        # prefix blocks too: suffix queries attend to them.
+        width = self._pow2_width(max(
+            -(-len(e) // self.page_size) for e in effs))
+
+        def stats():
+            out = {"rows": len(lens), "tokens": sum(lens),
+                   "keys": sum(n * s + n * (n + 1) // 2
+                               for n, s in zip(lens, starts)),
+                   "bucket": lb}
+            if self.kv_mode == "paged":
+                out["width"] = width
+            return out
+
+        with span("serve.prefill", stats):
+            tokens = np.zeros((nb, lb), np.int32)
+            lens_a = np.zeros(nb, np.int32)
+            starts_a = np.zeros(nb, np.int32)
+            valid = np.zeros(nb, bool)
+            for row, (eff, st) in enumerate(zip(effs, starts)):
+                tokens[row, :lens[row]] = eff[st:]
+                lens_a[row] = lens[row]
+                starts_a[row] = st
+                valid[row] = True
+            if self.kv_mode == "paged":
+                route = np.full((nb, width), -1, np.int32)
+                for row, slot in enumerate(slots):
+                    route[row] = self.page_table[slot, :width]
+                args = (self.params, jnp.asarray(tokens),
+                        jnp.asarray(lens_a), jnp.asarray(starts_a),
+                        jnp.asarray(route), jnp.asarray(valid), self.cache)
+            else:
+                # rows are blended into their target slot's ring row
+                # in-jit
+                route = np.zeros(nb, np.int32)
+                for row, slot in enumerate(slots):
+                    route[row] = slot
+                args = (self.params, jnp.asarray(tokens),
+                        jnp.asarray(lens_a), jnp.asarray(route),
+                        jnp.asarray(valid), self.cache)
+            args += (self._next_key(), jnp.float32(self.temperature))
+            self._stamp_prefill(reqs)
+            tok0, self.cache = self._prefill_step(*args)
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_tokens"] += sum(lens)
+            self.stats["prefill_positions"] += nb * lb
+            tok0 = np.asarray(tok0)
         for row, (slot, req) in enumerate(zip(slots, reqs)):
             self._finish_admit(slot, req, effs[row], int(tok0[row]))
 
@@ -921,27 +947,44 @@ class ServingEngine:
         eff = self._eff_prompt(req)
         t = len(eff)
         assert t < self.max_len, "admission rejects over-long prompts"
-        fresh = init_cache(self.cfg, 1, self.max_len, kv_bits=self._kv_bits)
-        self.cache = jax.tree.map(
-            lambda c, f: c.at[slot:slot + 1].set(f.astype(c.dtype)),
-            self.cache, fresh,
-        )
-        tokens = jnp.asarray(eff, jnp.int32)[None]
-        positions = jnp.arange(t, dtype=jnp.int32)[None]
-        row_cache = jax.tree.map(lambda c: c[slot:slot + 1], self.cache)
-        logits, row_cache2, _ = forward(
-            self.params, tokens, self.cfg,
-            positions=positions, cache=row_cache, cache_index=0,
-        )
-        self.cache = jax.tree.map(
-            lambda c, r: c.at[slot:slot + 1].set(r), self.cache, row_cache2
-        )
-        self.stats["per_row_prefill_calls"] += 1
-        tok0 = int(steps_mod.sample_tokens(
-            logits[:, -1], self._next_key(), jnp.float32(self.temperature),
-            fold=jnp.asarray([t - 1], jnp.int32),
-        )[0])
+        with span("serve.prefill", lambda: {
+                "rows": 1, "tokens": t, "keys": t * (t + 1) // 2,
+                "bucket": t}):
+            fresh = init_cache(self.cfg, 1, self.max_len,
+                               kv_bits=self._kv_bits)
+            self.cache = jax.tree.map(
+                lambda c, f: c.at[slot:slot + 1].set(f.astype(c.dtype)),
+                self.cache, fresh,
+            )
+            tokens = jnp.asarray(eff, jnp.int32)[None]
+            positions = jnp.arange(t, dtype=jnp.int32)[None]
+            row_cache = jax.tree.map(lambda c: c[slot:slot + 1], self.cache)
+            self._stamp_prefill([req])
+            logits, row_cache2, _ = forward(
+                self.params, tokens, self.cfg,
+                positions=positions, cache=row_cache, cache_index=0,
+            )
+            self.cache = jax.tree.map(
+                lambda c, r: c.at[slot:slot + 1].set(r), self.cache,
+                row_cache2,
+            )
+            self.stats["per_row_prefill_calls"] += 1
+            self.stats["prefill_tokens"] += t
+            self.stats["prefill_positions"] += t
+            tok0 = int(steps_mod.sample_tokens(
+                logits[:, -1], self._next_key(),
+                jnp.float32(self.temperature),
+                fold=jnp.asarray([t - 1], jnp.int32),
+            )[0])
         self._finish_admit(slot, req, eff, tok0)
+
+    def _stamp_prefill(self, reqs) -> None:
+        """``t_prefill``: the first prefill launch that holds a request
+        ends its wait (a preemption-resume keeps the first stamp)."""
+        now = self.clock()
+        for req in reqs:
+            if req.t_prefill is None:
+                req.t_prefill = now
 
     def _finish_admit(self, slot: int, req: Request, eff: np.ndarray,
                       tok0: int):
@@ -1088,29 +1131,30 @@ class ServingEngine:
         Copy-on-write happens at ADMISSION (``_paged_bind`` forks matched
         partial tails before the prefill write), so by the time decode
         runs, the cursor's page is always exclusive — asserted below."""
-        for i in np.nonzero(self.active)[0]:
-            if not self.active[i]:
-                continue  # preempted while serving an earlier grant
-            block = int(self.slot_pos[i]) // self.page_size
-            if block < int(self.slot_pages[i]):
-                # the cursor page must be exclusively held: shared full
-                # blocks always end at or before the prefill start (the
-                # cursor only moves forward from there), partial tails
-                # are COW-forked at admission, and decode-completed
-                # blocks are indexed only once the cursor has left them.
-                # Any future mapping path that breaks this must fork the
-                # page BEFORE the write (see _paged_bind) — fail loudly.
-                page = int(self.page_table[i, block])
-                assert self._allocator.refcount[page] == 1, (
-                    "write cursor reached a shared page", i, block, page)
-                continue
-            page = self._claim_reserved_page(int(i))
-            if page is None:
-                page = self._alloc_or_preempt(int(i))
-                if page is None:
+        with span("serve.grant"):
+            for i in np.nonzero(self.active)[0]:
+                if not self.active[i]:
+                    continue  # preempted while serving an earlier grant
+                block = int(self.slot_pos[i]) // self.page_size
+                if block < int(self.slot_pages[i]):
+                    # the cursor page must be exclusively held: shared full
+                    # blocks always end at or before the prefill start (the
+                    # cursor only moves forward from there), partial tails
+                    # are COW-forked at admission, and decode-completed
+                    # blocks are indexed only once the cursor has left them.
+                    # Any future mapping path that breaks this must fork the
+                    # page BEFORE the write (see _paged_bind) — fail loudly.
+                    page = int(self.page_table[i, block])
+                    assert self._allocator.refcount[page] == 1, (
+                        "write cursor reached a shared page", i, block, page)
                     continue
-            self._bind_next_page(int(i), page)
-        self._note_peak()
+                page = self._claim_reserved_page(int(i))
+                if page is None:
+                    page = self._alloc_or_preempt(int(i))
+                    if page is None:
+                        continue
+                self._bind_next_page(int(i), page)
+            self._note_peak()
 
     def _spec_lens(self) -> np.ndarray:
         """Per-slot draft budgets for this tick, with lookahead page
@@ -1198,25 +1242,46 @@ class ServingEngine:
     def step(self):
         """One engine tick: admit, grant pages, ONE fused decode (or one
         fused speculative draft+verify), retire."""
-        self._admit()
-        if not self.active.any():
-            return False
-        if self.kv_mode == "paged":
-            self._grant_pages()
+        with span("serve.tick", lambda: {
+                "clock": self.clock(), "active": int(self.active.sum())}):
+            self._admit()
             if not self.active.any():
-                return True  # progress: slots were preempted or retired
-        if self.decode_mode == "ragged" and self.speculative:
-            return self._step_speculative()
-        if self.decode_mode == "ragged":
-            next_ids, self.cache = self._ragged_step(
-                *self._decode_args(self._next_key()))
-            self.stats["decode_steps"] += 1
-            next_ids = np.asarray(next_ids)  # the ONE host sync per tick
-        else:
-            next_ids = self._decode_rows_reference()
-        for i in np.nonzero(self.active)[0]:
-            self._advance_slot(int(i), int(next_ids[i]))
-        return True
+                return False
+            if self.kv_mode == "paged":
+                self._grant_pages()
+                if not self.active.any():
+                    return True  # progress: slots were preempted or retired
+            if self.decode_mode == "ragged" and self.speculative:
+                return self._step_speculative()
+            if self.decode_mode == "ragged":
+                with span("serve.decode", lambda: self._decode_stats(0)):
+                    next_ids, self.cache = self._ragged_step(
+                        *self._decode_args(self._next_key()))
+                    self._count_decode()
+                    next_ids = np.asarray(next_ids)  # the ONE host sync
+            else:
+                next_ids = self._decode_rows_reference()
+            with span("serve.advance"):
+                for i in np.nonzero(self.active)[0]:
+                    self._advance_slot(int(i), int(next_ids[i]))
+            return True
+
+    def _decode_stats(self, spec: int) -> dict:
+        """``serve.decode`` span values: active rows, the keys they
+        attend to (each row's position + 1), the page-table width."""
+        act = self.active
+        out = {"rows": int(act.sum()),
+               "keys": int((self.slot_pos[act].astype(np.int64) + 1).sum()),
+               "spec": spec}
+        if self.kv_mode == "paged":
+            out["width"] = self._active_table().shape[1]
+        return out
+
+    def _count_decode(self) -> None:
+        """Count one decode step (plain or speculative) over the slots."""
+        self.stats["decode_steps"] += 1
+        self.stats["decode_rows"] += int(self.active.sum())
+        self.stats["decode_slots"] += self.max_batch
 
     def _decode_args(self, key) -> list:
         """Arguments of the plain (non-speculative) ragged decode step
@@ -1245,29 +1310,31 @@ class ServingEngine:
         spec_len = self._spec_lens()
         if not self.active.any():
             return True
-        out, n_acc, self.cache = self._spec_step(
-            self.params, self._draft_params,
-            jnp.asarray(self.slot_next[:, None]), self.cache,
-            jnp.asarray(self.slot_pos), jnp.asarray(self.active),
-            jnp.asarray(self._active_table()), jnp.asarray(spec_len),
-            self._next_key(), jnp.float32(self.temperature),
-        )
-        self.stats["decode_steps"] += 1
-        self.stats["spec_ticks"] += 1
-        out = np.asarray(out)      # the ONE host sync per tick
-        n_acc = np.asarray(n_acc)
-        for i in np.nonzero(self.active)[0]:
-            self.stats["draft_proposed"] += int(spec_len[i])
-            used = 0
-            for m in range(int(n_acc[i]) + 1):
-                used = m + 1
-                if self._advance_slot(int(i), int(out[i, m])):
-                    break
-            # accept rate counts drafts that became OUTPUT tokens: a
-            # slot retiring mid-run (eos / max_len) discards the rest of
-            # its accepted run, so the unconsumed tail must not inflate
-            # the reported rate
-            self.stats["draft_accepted"] += min(used, int(n_acc[i]))
+        with span("serve.decode", lambda: self._decode_stats(1)):
+            out, n_acc, self.cache = self._spec_step(
+                self.params, self._draft_params,
+                jnp.asarray(self.slot_next[:, None]), self.cache,
+                jnp.asarray(self.slot_pos), jnp.asarray(self.active),
+                jnp.asarray(self._active_table()), jnp.asarray(spec_len),
+                self._next_key(), jnp.float32(self.temperature),
+            )
+            self._count_decode()
+            self.stats["spec_ticks"] += 1
+            out = np.asarray(out)      # the ONE host sync per tick
+            n_acc = np.asarray(n_acc)
+        with span("serve.advance"):
+            for i in np.nonzero(self.active)[0]:
+                self.stats["draft_proposed"] += int(spec_len[i])
+                used = 0
+                for m in range(int(n_acc[i]) + 1):
+                    used = m + 1
+                    if self._advance_slot(int(i), int(out[i, m])):
+                        break
+                # accept rate counts drafts that became OUTPUT tokens: a
+                # slot retiring mid-run (eos / max_len) discards the rest
+                # of its accepted run, so the unconsumed tail must not
+                # inflate the reported rate
+                self.stats["draft_accepted"] += min(used, int(n_acc[i]))
         return True
 
     def _decode_rows_reference(self) -> np.ndarray:

@@ -1,11 +1,14 @@
 """Serving observability: latency metrics + Prometheus text snapshots.
 
-The engine stamps four timestamps on every :class:`~repro.serving.Request`
-(``t_submit``, ``t_admit``, ``t_first_token``, ``t_retire`` — see
-``engine.py``); this module turns them into the three latencies serving
-SLOs are written against, and renders the front door's counters, engine
-gauges and latency histograms as a Prometheus-style text snapshot:
+The engine stamps five timestamps on every :class:`~repro.serving.Request`
+(``t_submit``, ``t_prefill``, ``t_admit``, ``t_first_token``,
+``t_retire`` — see ``engine.py``); this module turns them into the
+latencies serving SLOs are written against, and renders the front
+door's counters, engine gauges and latency histograms as a
+Prometheus-style text snapshot:
 
+* **queue wait**: ``t_prefill - t_submit``. Time spent waiting before
+  the request's prefill program was launched.
 * **TTFT** (time to first token): ``t_first_token - t_submit``. Queue
   wait plus prefill — the latency admission policies actually control.
 * **TPOT** (time per output token): ``(t_retire - t_first_token) /
@@ -30,6 +33,13 @@ import numpy as np
 
 
 # -- per-request latencies --------------------------------------------------
+def queue_wait_s(req) -> Optional[float]:
+    """Submission to prefill launch, or None if no prefill held it."""
+    if req.t_prefill is None or req.t_submit is None:
+        return None
+    return req.t_prefill - req.t_submit
+
+
 def ttft_s(req) -> Optional[float]:
     """Time to first token, or None if the request never produced one."""
     if req.t_first_token is None or req.t_submit is None:

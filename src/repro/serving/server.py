@@ -26,10 +26,12 @@ deployment actually exposes:
   async iterator; the serve loop pushes each generated token the tick
   it appears.
 * **Observability** — the engine stamps per-request timestamps
-  (arrival, admit, first token, retire); the server aggregates them
-  into TTFT/TPOT/e2e histograms and renders a Prometheus-style text
-  snapshot (``metrics_snapshot``) on top of the engine's ``.stats``
-  counters and page-pool gauges.
+  (arrival, prefill launch, admit, first token, retire); the server
+  aggregates them into queue-wait/TTFT/TPOT/e2e histograms and renders
+  a Prometheus-style text snapshot (``metrics_snapshot``) on top of the
+  engine's ``.stats`` counters, the process's compile count and the
+  page-pool gauges. ``serve.dispatch`` and ``serve.publish`` trace
+  spans mark the loop's own work (``tracing.span``).
 
 The engine tick itself runs via ``asyncio.to_thread`` so arrivals keep
 flowing while a step computes (jax releases the GIL inside compiled
@@ -63,6 +65,7 @@ import numpy as np
 from repro.configs.base import ShapeConfig
 from repro.launch.analytic_costs import cell_cost
 from repro.serving import metrics as metrics_mod
+from repro.serving import tracing
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.scheduler import QueueEntry, make_policy
 
@@ -229,6 +232,7 @@ class AsyncServer:
             "rejected_engine": 0,
         }
         self.histograms = {
+            "samd_request_queue_wait_seconds": metrics_mod.Histogram(),
             "samd_request_ttft_seconds": metrics_mod.Histogram(),
             "samd_request_tpot_seconds": metrics_mod.Histogram(),
             "samd_request_e2e_seconds": metrics_mod.Histogram(),
@@ -360,12 +364,14 @@ class AsyncServer:
         eng = self.engine
         now = self.clock()
         free = sum(1 for s in eng.slots if s is None) - len(eng.queue)
-        while self._waiting and free > 0:
-            idx = self.policy.select(self._waiting, now)
-            stream = self._waiting.pop(idx).payload
-            self._inflight[id(stream.request)] = stream
-            eng.submit(stream.request)
-            free -= 1
+        with tracing.span("serve.dispatch", lambda: {
+                "dispatched": min(len(self._waiting), max(free, 0))}):
+            while self._waiting and free > 0:
+                idx = self.policy.select(self._waiting, now)
+                stream = self._waiting.pop(idx).payload
+                self._inflight[id(stream.request)] = stream
+                eng.submit(stream.request)
+                free -= 1
         if not self._engine_busy():
             return False
         if self.step_in_thread:
@@ -378,53 +384,58 @@ class AsyncServer:
     def _publish(self) -> None:
         """Push this tick's new tokens into their streams and finalize
         retirements (runs on the event-loop thread)."""
-        eng = self.engine
-        for req in eng.slots:
-            if req is not None:
-                stream = self._inflight.get(id(req))
-                if stream is not None:
-                    stream._push_new()
-        while self._finished_seen < len(eng.finished):
-            req = eng.finished[self._finished_seen]
-            self._finished_seen += 1
-            stream = self._inflight.pop(id(req), None)
-            if stream is None:
-                continue  # not front-door traffic (direct engine use)
-            stream._push_new()
-            stream._finish()
-            self.finished.append(req)
-            if req.error is not None:
-                # admitted here but refused by the engine (e.g. a race
-                # on pool feasibility): surfaced via the stream's
-                # request.error, counted separately from completions
-                self.counters["rejected_engine"] += 1
-                continue
-            self.counters["completed"] += 1
-            for name, fn in (
-                ("samd_request_ttft_seconds", metrics_mod.ttft_s),
-                ("samd_request_tpot_seconds", metrics_mod.tpot_s),
-                ("samd_request_e2e_seconds", metrics_mod.e2e_s),
-            ):
-                v = fn(req)
-                if v is not None:
-                    self.histograms[name].observe(v)
-            if (
-                stream.deadline_s is not None
-                and req.t_retire is not None
-                and req.t_retire > stream.deadline_s
-            ):
-                self.counters["deadline_missed"] += 1
+        with tracing.span("serve.publish"):
+            eng = self.engine
+            for req in eng.slots:
+                if req is not None:
+                    stream = self._inflight.get(id(req))
+                    if stream is not None:
+                        stream._push_new()
+            while self._finished_seen < len(eng.finished):
+                req = eng.finished[self._finished_seen]
+                self._finished_seen += 1
+                stream = self._inflight.pop(id(req), None)
+                if stream is None:
+                    continue  # not front-door traffic (direct engine use)
+                stream._push_new()
+                stream._finish()
+                self.finished.append(req)
+                if req.error is not None:
+                    # admitted here but refused by the engine (e.g. a race
+                    # on pool feasibility): surfaced via the stream's
+                    # request.error, counted separately from completions
+                    self.counters["rejected_engine"] += 1
+                    continue
+                self.counters["completed"] += 1
+                for name, fn in (
+                    ("samd_request_queue_wait_seconds",
+                     metrics_mod.queue_wait_s),
+                    ("samd_request_ttft_seconds", metrics_mod.ttft_s),
+                    ("samd_request_tpot_seconds", metrics_mod.tpot_s),
+                    ("samd_request_e2e_seconds", metrics_mod.e2e_s),
+                ):
+                    v = fn(req)
+                    if v is not None:
+                        self.histograms[name].observe(v)
+                if (
+                    stream.deadline_s is not None
+                    and req.t_retire is not None
+                    and req.t_retire > stream.deadline_s
+                ):
+                    self.counters["deadline_missed"] += 1
 
     # -- observability -----------------------------------------------------
     def metrics_snapshot(self) -> str:
         """Prometheus-style text snapshot: front-door counters, engine
-        tick counters (``.stats``), page-pool and queue gauges, and the
-        TTFT/TPOT/e2e histograms."""
+        tick counters (``.stats``), the process's backend compiles,
+        page-pool and queue gauges, and the queue-wait/TTFT/TPOT/e2e
+        histograms."""
         eng = self.engine
         counters = {
             f"samd_server_{k}_total": v
             for k, v in self.counters.items()
         }
+        counters["samd_process_compiles_total"] = tracing.compiles()
         for k, v in eng.stats.items():
             if k != "peak_pages_used":
                 counters[f"samd_engine_{k}_total"] = v

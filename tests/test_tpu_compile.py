@@ -14,6 +14,7 @@ file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,10 +106,41 @@ CASES = {
 }
 
 
+# the op name the device trace gives each kernel (``%<name>.<n>``); the
+# chip benchmark's readers find a kernel's events by it
+KERNEL_NAMES = {
+    "paged_decode": "paged_decode_attention",
+    "paged_verify": "paged_verify_attention",
+    "samd_matmul": "samd_matmul",
+    "samd_conv2d": "samd_conv2d",
+}
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """The compiled program text of a case, compiled once per module."""
+    texts = {}
+
+    def get(case):
+        if case not in texts:
+            fn, shapes = CASES[case]()
+            args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                    for s, dt in shapes]
+            texts[case] = jax.jit(fn).lower(*args).compile().as_text()
+        return texts[case]
+
+    return get
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_for_v5e(case, one_chip):
-    fn, shapes = CASES[case]()
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-            for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), case
+def test_kernel_compiles_for_v5e(case, compiled):
+    assert "tpu_custom_call" in compiled(case), case
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_instruction_name(case, compiled):
+    want = next(v for k, v in KERNEL_NAMES.items() if case.startswith(k))
+    names = re.findall(r"%([\w.\-]+) = [^\n]*tpu_custom_call",
+                       compiled(case))
+    assert names, case
+    assert {re.sub(r"\.\d+$", "", n) for n in names} == {want}, names
