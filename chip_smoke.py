@@ -137,11 +137,14 @@ def _paged_pools(rng, cfg, sizes, packed):
     b, ps = sizes.max_batch, sizes.page_size
     n_pp = sizes.max_len // ps
     pages = b * n_pp
+    # heads folded into the minor dim, as init_paged_cache stores them
     shape = (pages, ps, cfg.n_kv_heads, cfg.head_dim)
+    folded = (pages, ps, -1)
     if packed:
         def pool():
             vals = rng.integers(-127, 128, size=shape)
-            return pack_int8_lanes(jnp.asarray(vals, jnp.int8))
+            words = pack_int8_lanes(jnp.asarray(vals, jnp.int8))
+            return words.reshape(folded)
 
         def scale():
             return jnp.asarray(
@@ -150,7 +153,8 @@ def _paged_pools(rng, cfg, sizes, packed):
         kp, vp, ks, vs = pool(), pool(), scale(), scale()
     else:
         def pool():
-            return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+            vals = rng.normal(size=shape).reshape(folded)
+            return jnp.asarray(vals, jnp.bfloat16)
 
         kp, vp, ks, vs = pool(), pool(), None, None
     perm = rng.permutation(pages)

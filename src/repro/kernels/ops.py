@@ -161,8 +161,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            interpret: bool | None = None) -> jax.Array:
     """Fused decode attention over the paged KV pool (no gathered copy).
 
-    q [B, H, dh] -> [B, H, dh]. Pools are bf16/f32 pages, or SAMD-packed
-    uint32 pages (+ per-(token, head) scales) unpacked inside the kernel.
+    q [B, H, dh] -> [B, H, dh]. Pools are folded [P, page_size, Hkv * w]
+    bf16/f32 pages (w = dh), or SAMD-packed uint32 pages (w = dh // 4,
+    + per-(token, head) scales) unpacked inside the kernel.
 
     Backend dispatch differs from the other kernels here: on TPU the
     Pallas kernel compiles to Mosaic, but on CPU the default is the

@@ -30,17 +30,20 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     derived key positions, softmax runs in f32 over the whole view. This
     is exactly the dense copy the fused kernel exists to delete."""
     b, n_pp = page_table.shape
-    p, page_size, hkv = k_pages.shape[:3]
-    h = q.shape[1]
+    p, page_size, lanes = k_pages.shape
+    h, dh = q.shape[1:]
+    packed = k_pages.dtype == jnp.uint32
+    hkv = lanes * (4 if packed else 1) // dh
     g = h // hkv
 
     safe = jnp.clip(page_table.astype(jnp.int32), 0, p - 1).reshape(-1)
 
     def gather(pool, scale):
+        # folded [P, page_size, Hkv * w] pages: heads split after the gather
         gathered = jnp.take(pool, safe, axis=0).reshape(
-            (b, n_pp * page_size) + pool.shape[2:]
+            b, n_pp * page_size, hkv, -1
         )
-        if pool.dtype == jnp.uint32:
+        if packed:
             gathered = unpack_int8_lanes(gathered).astype(jnp.float32)
             gathered = gathered * jnp.take(scale, safe, axis=0).reshape(
                 b, n_pp * page_size, hkv

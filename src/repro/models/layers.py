@@ -206,9 +206,16 @@ def _cache_write(buf: jax.Array, val: jax.Array, cache_index, s: int):
 # paged KV cache (vLLM-style block tables over a global page pool)
 # ---------------------------------------------------------------------------
 #
-# Pool layout: each attention layer owns pool tensors [P, page_size, ...]
-# (P pages shared by ALL slots). A host-managed page table [B, n_pp] maps a
-# slot's logical block index to a pool page; -1 marks an unallocated block.
+# Pool layout: each attention layer owns K and V pools [P, page_size,
+# Hkv * w] (P pages shared by ALL slots), heads folded into the minor dim:
+# w = head_dim for bf16/f32 pools, head_dim // 4 for SAMD-packed uint32
+# pools (packed pools add per-(token, head) scale pools [P, page_size,
+# Hkv]). The folded minor dim is a whole number of 128-lane tiles at real
+# widths, so the step programs keep the pool in XLA's default layout and
+# the scatter, the gather and the kernel take it as stored; heads are
+# split only in gathered [B, T, Hkv, w] views. A host-managed page table
+# [B, n_pp] maps a slot's logical block index to a pool page; -1 marks an
+# unallocated block.
 # Token at logical position t of slot b lives at pool page
 # ``page_table[b, t // page_size]``, offset ``t % page_size``.
 #
@@ -246,14 +253,16 @@ def _paged_write(pool: jax.Array, val: jax.Array, page_table: jax.Array,
                  positions: jax.Array, page_size: int) -> jax.Array:
     """Scatter ``val`` [B, S, ...] into ``pool`` [P, page_size, ...] at the
     slots named by (page_table, positions) — the paged generalization of
-    the ragged ``_cache_write``. Invalid positions are dropped."""
+    the ragged ``_cache_write``. Each token's trailing dims are folded to
+    the pool's row shape (``[Hkv, w]`` -> ``Hkv * w``). Invalid positions
+    are dropped."""
     p = pool.shape[0]
     with jax.named_scope("kv_write"):
         flat = pool.reshape((p * page_size,) + pool.shape[2:])
         idx = _paged_flat_index(page_table, positions, page_size,
                                 p * page_size)
         out = flat.at[idx.reshape(-1)].set(
-            val.astype(pool.dtype).reshape((-1,) + val.shape[2:]),
+            val.astype(pool.dtype).reshape((-1,) + pool.shape[2:]),
             mode="drop",
         )
         return out.reshape(pool.shape)
@@ -284,15 +293,21 @@ def _paged_key_positions(page_table: jax.Array, page_size: int) -> jax.Array:
 
 
 def _gathered_pool_kv(pool: dict, page_table: jax.Array, page_size: int,
-                      dtype) -> tuple:
+                      n_kv_heads: int, dtype) -> tuple:
     """Dense per-row gather of a KV pool into contiguous
-    [B, n_pp * page_size, Hkv, dh] K/V views. SAMD-packed uint32 pools
-    are lane-unpacked and rescaled after the gather — the ONE reference
-    view shared by the gather decode path and the speculative draft's
-    pool read, so the packed-page layout is interpreted in one place."""
+    [B, n_pp * page_size, Hkv, dh] K/V views. Heads are split from the
+    folded rows after the gather; SAMD-packed uint32 pools are then
+    lane-unpacked and rescaled — the ONE reference view shared by the
+    gather decode path and the speculative draft's pool read, so the
+    page layout is interpreted in one place."""
+
+    def gather(x):
+        g = _paged_gather(x, page_table, page_size)
+        return g.reshape(g.shape[:2] + (n_kv_heads, -1))
+
     if pool["k"].dtype in (jnp.int8, jnp.uint32):
-        kg = _paged_gather(pool["k"], page_table, page_size)
-        vg = _paged_gather(pool["v"], page_table, page_size)
+        kg = gather(pool["k"])
+        vg = gather(pool["v"])
         ksg = _paged_gather(pool["k_scale"], page_table, page_size)
         vsg = _paged_gather(pool["v_scale"], page_table, page_size)
         k_full = (unpack_int8_lanes(kg).astype(jnp.float32)
@@ -300,8 +315,7 @@ def _gathered_pool_kv(pool: dict, page_table: jax.Array, page_size: int,
         v_full = (unpack_int8_lanes(vg).astype(jnp.float32)
                   * vsg[..., None]).astype(dtype)
         return k_full, v_full
-    return (_paged_gather(pool["k"], page_table, page_size).astype(dtype),
-            _paged_gather(pool["v"], page_table, page_size).astype(dtype))
+    return gather(pool["k"]).astype(dtype), gather(pool["v"]).astype(dtype)
 
 
 def attention_block(
@@ -329,11 +343,12 @@ def attention_block(
     serving batches stay inside a single compiled step.
 
     When ``page_table`` is given, ``kv_cache`` leaves are page pools
-    [P, page_size, ...] instead of per-slot rings [B, T, ...]: writes
-    scatter through the table at each token's logical position (the
-    ``(page, offset)`` generalization of the ragged ``(row, offset)``
-    writes). ``cache_index`` is ignored — ``positions`` already names
-    every written token's offset. With ``paged_attn="fused"`` (decode
+    [P, page_size, Hkv * w] (heads folded into the minor dim; see the
+    pool-layout comment above ``_paged_flat_index``) instead of per-slot
+    rings [B, T, ...]: writes scatter through the table at each token's
+    logical position (the ``(page, offset)`` generalization of the ragged
+    ``(row, offset)`` writes). ``cache_index`` is ignored — ``positions``
+    already names every written token's offset. With ``paged_attn="fused"`` (decode
     only, S == 1) attention runs the Pallas paged-attention kernel
     straight off the pool — no gathered [B, n_pp * page_size] copy;
     ``paged_attn="gather"`` keeps the per-row page gather as the
@@ -388,7 +403,7 @@ def attention_block(
         k_pos_pool = jnp.where(
             k_pos_pool <= pool_bound[:, None], k_pos_pool, -1)
         pool_k, pool_v = _gathered_pool_kv(pool_kv, page_table,
-                                           page_size, q.dtype)
+                                           page_size, hkv, q.dtype)
         k_full = jnp.concatenate([pool_k, ck.astype(q.dtype)], axis=1)
         v_full = jnp.concatenate([pool_v, cv.astype(q.dtype)], axis=1)
         k_pos = jnp.concatenate([k_pos_pool, cpos], axis=1)
@@ -453,7 +468,7 @@ def attention_block(
             else:
                 k_pos = _paged_key_positions(page_table, page_size)
                 k_full, v_full = _gathered_pool_kv(new_cache, page_table,
-                                                   page_size, q.dtype)
+                                                   page_size, hkv, q.dtype)
                 att = attention(q, k_full, v_full, positions, k_pos,
                                 chunk=chunk)
         elif quantized_kv:

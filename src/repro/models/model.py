@@ -312,6 +312,15 @@ def init_paged_cache(cfg: ArchConfig, num_pages: int, page_size: int,
     only (recurrent state is O(1) per slot — nothing to page). No per-token
     ``pos`` buffer: key validity is derived from the page table plus
     causality (see layers._paged_key_positions).
+
+    K and V pools are ``[num_pages, page_size, n_kv_heads * w]``: heads
+    folded into the minor dim, ``w`` = head_dim for ``dtype`` pools and
+    head_dim // 4 for SAMD-packed uint32 pools (``kv_bits=8``), whose
+    per-(token, head) scale pools are ``[num_pages, page_size,
+    n_kv_heads]``. At real widths the minor dim is a whole number of
+    128-lane tiles, so XLA stores the pool unpadded in its default layout
+    and the step programs never convert it (see layers' pool-layout
+    comment). ``stacked`` adds a leading layer axis.
     """
     if cfg.family not in ("dense", "moe"):
         raise ValueError(
@@ -319,19 +328,20 @@ def init_paged_cache(cfg: ArchConfig, num_pages: int, page_size: int,
         )
 
     def kv_pool():
-        shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        heads = (num_pages, page_size, cfg.n_kv_heads)
         if kv_bits == 8:
             # SAMD-packed int8 pages: uint32 words of four 8-bit lanes
             # along head_dim (same bytes as int8, but the paged-attention
             # kernel reads whole words and unpacks lanes on the VPU)
             assert cfg.head_dim % 4 == 0, cfg.head_dim
-            packed = shape[:3] + (cfg.head_dim // 4,)
+            packed = heads[:2] + (cfg.n_kv_heads * cfg.head_dim // 4,)
             return {
                 "k": jnp.zeros(packed, jnp.uint32),
                 "v": jnp.zeros(packed, jnp.uint32),
-                "k_scale": jnp.zeros(shape[:3], jnp.float32),
-                "v_scale": jnp.zeros(shape[:3], jnp.float32),
+                "k_scale": jnp.zeros(heads, jnp.float32),
+                "v_scale": jnp.zeros(heads, jnp.float32),
             }
+        shape = heads[:2] + (cfg.n_kv_heads * cfg.head_dim,)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     if stacked:

@@ -122,13 +122,19 @@ def pack_int8_lanes(vals: jax.Array) -> jax.Array:
     return jnp.sum(u << shifts, axis=-1, dtype=jnp.uint32)
 
 
+def int8_lane(words: jax.Array, lane: int) -> jax.Array:
+    """Lane ``lane`` (0-3) of uint32 words as sign-extended int32, same
+    shape as ``words``: element ``4 * w + lane`` of the vector that
+    ``pack_int8_lanes`` packed. The paged-attention kernel reads one lane
+    of a whole row of words at a time, so the row never changes shape."""
+    v = ((words >> jnp.uint32(8 * lane)) & jnp.uint32(0xFF)).astype(jnp.int32)
+    return v - ((v >> 7) & 1) * 256
+
+
 def unpack_int8_lanes(words: jax.Array) -> jax.Array:
     """uint32 [..., W] -> sign-extended int32 [..., W*4] (inverse of
-    ``pack_int8_lanes``). One broadcasted shift/mask chain over the four
-    lanes — the same vectorized idiom the samd_matmul kernel uses."""
-    shifts = jnp.arange(4, dtype=jnp.uint32) * jnp.uint32(8)
-    v = ((words[..., None] >> shifts) & jnp.uint32(0xFF)).astype(jnp.int32)
-    v = v - ((v >> 7) & 1) * 256
+    ``pack_int8_lanes``)."""
+    v = jnp.stack([int8_lane(words, j) for j in range(4)], axis=-1)
     return v.reshape(words.shape[:-1] + (words.shape[-1] * 4,))
 
 

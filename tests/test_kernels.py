@@ -79,11 +79,13 @@ def test_samd_matmul_batched_lead_dims():
 # ---------------------------------------------------------------------------
 
 def _paged_pools(rng, P, ps, hkv, dh, packed):
-    """Random pools in either operand layout: bf16 pages, or SAMD-packed
-    uint32 pages (+ per-(token, head) scales)."""
+    """Random pools in either operand layout, heads folded into the minor
+    dim as ``init_paged_cache`` stores them: bf16 pages [P, ps, hkv * dh],
+    or SAMD-packed uint32 pages [P, ps, hkv * dh // 4] (+ per-(token,
+    head) scales [P, ps, hkv])."""
     if not packed:
-        kp = jnp.asarray(rng.normal(size=(P, ps, hkv, dh)), jnp.bfloat16)
-        vp = jnp.asarray(rng.normal(size=(P, ps, hkv, dh)), jnp.bfloat16)
+        kp = jnp.asarray(rng.normal(size=(P, ps, hkv * dh)), jnp.bfloat16)
+        vp = jnp.asarray(rng.normal(size=(P, ps, hkv * dh)), jnp.bfloat16)
         return kp, vp, None, None
     from repro.quant.packing import pack_int8_lanes
 
@@ -93,8 +95,8 @@ def _paged_pools(rng, P, ps, hkv, dh, packed):
                      jnp.float32)
     vs = jnp.asarray(np.abs(rng.normal(size=(P, ps, hkv))) * 0.01 + 1e-4,
                      jnp.float32)
-    return (pack_int8_lanes(jnp.asarray(k8)), pack_int8_lanes(jnp.asarray(v8)),
-            ks, vs)
+    return (pack_int8_lanes(jnp.asarray(k8)).reshape(P, ps, -1),
+            pack_int8_lanes(jnp.asarray(v8)).reshape(P, ps, -1), ks, vs)
 
 
 @pytest.mark.parametrize("lowering", ["pallas", "xla"])
@@ -149,7 +151,8 @@ def test_paged_attention_first_token_single_key():
     pt = jnp.asarray([[2, -1]], jnp.int32)
     got = ops.paged_decode_attention(q, kp, vp, pt,
                                      jnp.asarray([0], jnp.int32))
-    want = np.asarray(vp, np.float32)[2, 0]  # [hkv, dh], page 2 offset 0
+    # page 2 offset 0, heads split from the folded row
+    want = np.asarray(vp, np.float32)[2, 0].reshape(hkv, dh)
     np.testing.assert_allclose(np.asarray(got[0], np.float32), want,
                                rtol=2e-2, atol=2e-2)
 
@@ -174,8 +177,8 @@ def test_paged_attention_kv_head_blocking(block_kv_heads):
     as the oracle (one program per (slot, head-block))."""
     P, ps, hkv, dh, n_pp = 8, 4, 4, 8, 3
     rng = np.random.default_rng(block_kv_heads)
-    kp = jnp.asarray(rng.normal(size=(P, ps, hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, ps, hkv, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, ps, hkv * dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, ps, hkv * dh)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(2, hkv, dh)), jnp.float32)
     pt = jnp.asarray([[0, 5, 2], [7, -1, -1]], jnp.int32)
     pos = jnp.asarray([10, 3], jnp.int32)
@@ -185,6 +188,57 @@ def test_paged_attention_kv_head_blocking(block_kv_heads):
     want = ref.paged_attention_ref(q, kp, vp, pt, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("lowering", ["pallas", "xla"])
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["bf16", "int8_packed"])
+def test_paged_attention_folded_pool_matches_unfolded_kv(packed, lowering):
+    """The folded pool [P, ps, hkv * w] is only a storage order: decode
+    attention over it must equal plain softmax attention computed in
+    numpy from the same K/V held as [P, ps, hkv, dh] (grouped queries,
+    g = 2, and a kv-head count whose lanes do not fill a tile)."""
+    from repro.quant.packing import pack_int8_lanes
+
+    P, ps, hkv, dh, g = 6, 8, 3, 16, 2
+    rng = np.random.default_rng(31 + packed)
+    if packed:
+        k8 = rng.integers(-127, 128, size=(P, ps, hkv, dh))
+        v8 = rng.integers(-127, 128, size=(P, ps, hkv, dh))
+        ks = rng.uniform(0.002, 0.02, size=(P, ps, hkv))
+        vs = rng.uniform(0.002, 0.02, size=(P, ps, hkv))
+        k4, v4 = k8 * ks[..., None], v8 * vs[..., None]
+
+        def fold(x8):
+            words = pack_int8_lanes(jnp.asarray(x8, jnp.int8))
+            return words.reshape(P, ps, hkv * dh // 4)
+
+        kp, vp = fold(k8), fold(v8)
+        ks, vs = jnp.asarray(ks, jnp.float32), jnp.asarray(vs, jnp.float32)
+    else:
+        k4 = rng.normal(size=(P, ps, hkv, dh)).astype(np.float32)
+        v4 = rng.normal(size=(P, ps, hkv, dh)).astype(np.float32)
+        kp = jnp.asarray(k4.reshape(P, ps, hkv * dh))
+        vp = jnp.asarray(v4.reshape(P, ps, hkv * dh))
+        ks = vs = None
+    q = rng.normal(size=(2, hkv * g, dh)).astype(np.float32)
+    table = np.asarray([[4, 1, 3], [2, 5, -1]], np.int32)
+    pos = np.asarray([19, 10], np.int32)
+    got = np.asarray(ops.paged_decode_attention(
+        jnp.asarray(q), kp, vp, jnp.asarray(table), jnp.asarray(pos),
+        k_scale=ks, v_scale=vs,
+        interpret=True if lowering == "pallas" else None,
+    ), np.float32)
+    for i in range(2):
+        keys = np.concatenate([k4[p] for p in table[i] if p >= 0])
+        vals = np.concatenate([v4[p] for p in table[i] if p >= 0])
+        keys, vals = keys[:pos[i] + 1], vals[:pos[i] + 1]  # [T, hkv, dh]
+        for h in range(hkv * g):
+            s = keys[:, h // g] @ q[i, h] / np.sqrt(dh)
+            w = np.exp(s - s.max())
+            want = (w / w.sum()) @ vals[:, h // g]
+            np.testing.assert_allclose(got[i, h], want, rtol=1e-4,
+                                       atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

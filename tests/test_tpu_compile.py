@@ -61,7 +61,8 @@ def _attention(packed, verify):
     """Paged decode (or S=3 verify) attention over a full-width pool."""
     h, hkv, dh = QWEN.n_heads, QWEN.n_kv_heads, QWEN.head_dim
     width = dh // 4 if packed else dh
-    pool = ((PAGES, PAGE, hkv, width), jnp.uint32 if packed else jnp.bfloat16)
+    pool = ((PAGES, PAGE, hkv * width),
+            jnp.uint32 if packed else jnp.bfloat16)
     q = ((BATCH, 3, h, dh) if verify else (BATCH, h, dh), jnp.bfloat16)
     pos = ((BATCH, 3) if verify else (BATCH,), jnp.int32)
     scales = [((PAGES, PAGE, hkv), jnp.float32)] * 2 if packed else []
@@ -144,3 +145,77 @@ def test_kernel_instruction_name(case, compiled):
                        compiled(case))
     assert names, case
     assert {re.sub(r"\.\d+$", "", n) for n in names} == {want}, names
+
+
+# ---------------------------------------------------------------------------
+# the step programs keep the KV pools in the layout they are stored in
+# ---------------------------------------------------------------------------
+
+SERVE_PAGES = 2048  # the chip benchmark's pool: 32768 tokens a layer
+
+
+@pytest.fixture(scope="module")
+def step_programs(one_chip):
+    """Optimized program text of the paged ragged decode step and the
+    paged prefill step of a two-layer Qwen1.5-0.5B at published widths,
+    pools donated as the engine donates them, and the pool shapes."""
+    from repro.configs.base import RunConfig, ShapeConfig
+    from repro.kernels import ops
+    from repro.launch import steps
+    from repro.models import (
+        build_template, init_paged_cache, shape_dtype_from_spec,
+    )
+
+    cfg = QWEN.scaled(n_layers=2)
+    run = RunConfig(arch=cfg, shape=ShapeConfig("serve", MAX_LEN, BATCH,
+                                                "decode"))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(shape_dtype_from_spec(build_template(cfg,
+                                                          stacked=False)))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_cache(cfg, SERVE_PAGES, PAGE)))
+    key, temp = arg((2,), jnp.uint32), arg((), jnp.float32)
+    rows, flags = arg((BATCH,), jnp.int32), arg((BATCH,), jnp.bool_)
+    # ops dispatch sees the CPU: steer it to the Mosaic kernel
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_default_interpret", lambda: False)
+        decode = jax.jit(
+            steps.make_paged_ragged_serve_step(cfg, run, PAGE),
+            donate_argnums=(2,),
+        ).lower(params, arg((BATCH, 1), jnp.int32), cache, rows, flags,
+                arg((BATCH, MAX_LEN // PAGE), jnp.int32), key, temp)
+        prefill = jax.jit(
+            steps.make_paged_prefill_step(cfg, run, PAGE),
+            donate_argnums=(6,),
+        ).lower(params, arg((BATCH, 256), jnp.int32), rows, rows,
+                arg((BATCH, 256 // PAGE), jnp.int32), flags, cache, key,
+                temp)
+        texts = {"decode": decode.compile().as_text(),
+                 "prefill": prefill.compile().as_text()}
+    pools = {tuple(x.shape) for x in jax.tree.leaves(cache)}
+    return texts, pools
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_step_program_copies_no_kv_pool(program, step_programs):
+    """A pool the compiler lays out other than as it is stored is copied
+    into that layout and back on every call: with [P, 16, 16, 64] pools,
+    four pool-sized copies a layer. Folded [P, 16, 1024] pools need none."""
+    texts, pools = step_programs
+    text = texts[program]
+    copies = [
+        tuple(int(d) for d in m.group(1).split(","))
+        for m in re.finditer(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(", text)
+    ]
+    assert copies, "no copy at all: the pattern no longer reads the HLO"
+    assert [c for c in copies if c in pools] == [], pools
+    if program == "decode":
+        assert "tpu_custom_call" in text

@@ -72,7 +72,9 @@ DEFAULT_CONFIG = {
         "ow": 226, "wp": 226,               # VGG-B 224 + 2*padding
         "bc": 1024, "bcw": 128, "vpw": 16,  # conv channel block
         "bh": 8, "g": 32, "dh": 256, "sq": 8,  # paged attention
-        "page_size": 16, "kv_width": 256,
+        "page_size": 16,
+        # folded rows: rows = sq * g, planes * lanes = bh * dh
+        "rows": 256, "planes": 1, "lanes": 2048,
     },
     "dtype_bytes": {
         "float32": 4, "int32": 4, "uint32": 4,
