@@ -11,11 +11,17 @@ Scheduling model (this module's contract):
     token, active flag, page table) lives in numpy and is synced to the
     device once per tick;
   * admission runs ONE bucket-padded batched prefill over all admitted
-    requests (attention families; recurrent families fall back to per-slot
-    exact-length prefill, since right-padding would pollute positionless
-    recurrent state). Prompts with ``len(prompt) >= max_len`` are REJECTED
-    gracefully — the request lands in ``finished`` with ``error`` set and
-    no tokens, and every other in-flight request keeps serving;
+    requests in a ``[max_batch, bucket]`` block (attention families;
+    recurrent families fall back to per-slot exact-length prefill, since
+    right-padding would pollute positionless recurrent state). Under
+    paged KV each unshared suffix is cut into chunks spread over the
+    rows no request holds, so the bucket is the smallest power of two
+    at which the chunks fit the block, not the longest suffix's (a lone
+    request fills the block instead of one row of it); the ring keeps
+    one row per request. Prompts with ``len(prompt) >= max_len`` are
+    REJECTED gracefully — the request lands in ``finished`` with
+    ``error`` set and no tokens, and every other in-flight request keeps
+    serving;
   * every engine tick runs ONE position-ragged fused decode step over the
     whole slot set: per-row KV reads/writes are vectorized scatters inside
     the jit, so mixed-position batches — the normal state right after a
@@ -118,11 +124,11 @@ automatic fallback for recurrent families and ``decode_mode="per_row"``);
 baseline. ``ServingEngine.stats`` counts compiled-step, per-row-forward,
 page-grant, prefix-hit, COW-fork, preemption and OOP-retire events, the
 prefill and decode work done against the work computed (prompt tokens
-written vs positions, active rows vs rows), plus the peak page-pool
-occupancy, so tests can assert the hot path stays fused and pool
-pressure (and the sharing win) is visible. ``serve.*`` trace spans
-(``serving/tracing.py``) mark each phase of a tick while the JAX
-profiler records.
+written vs positions, active rows vs rows, rows a split suffix took),
+plus the peak page-pool occupancy, so tests can assert the hot path
+stays fused and pool pressure (and the sharing win) is visible.
+``serve.*`` trace spans (``serving/tracing.py``) mark each phase of a
+tick while the JAX profiler records.
 """
 from __future__ import annotations
 
@@ -331,14 +337,39 @@ class PageAllocator:
         self.reserved = 0
 
 
-def _bucket_len(max_prompt: int, max_len: int) -> int:
-    """Smallest power-of-two prefill bucket >= the longest admitted prompt
-    (floor 8, capped at the cache length) — bounds jit retraces to
-    O(log max_len) shapes."""
+def _prefill_rows(lens: list[int], rows: int, max_len: int):
+    """Lay admitted suffixes of ``lens`` tokens into a ``[rows, lb]``
+    prefill block, spreading each over rows no request holds.
+
+    ``lb`` is the smallest power of two (floor 8, capped at the cache
+    length; powers of two bound jit retraces to O(log max_len) shapes)
+    at which cutting every suffix into consecutive chunks of ``lb``
+    tokens needs at most ``rows`` rows; one row per request always
+    fits, so ``lb`` never exceeds the longest suffix's own bucket.
+    Request i's LAST chunk sits on row i, where its first token is
+    sampled (folded by row i and its last position, as when it held the
+    row alone); its earlier chunks fill rows ``len(lens)..`` in request
+    order. Returns ``(lb, plan)``, plan a list of ``(row, request,
+    offset, length)`` with offsets counted from the request's first
+    unshared token. ``rows=len(lens)`` gives one row per request at the
+    longest suffix's bucket."""
+    assert 1 <= len(lens) <= rows, (lens, rows)
+
+    def chunks(n, lb):
+        return max(1, -(-n // lb))
+
     lb = 8
-    while lb < max_prompt:
+    while sum(chunks(n, min(lb, max_len)) for n in lens) > rows:
         lb *= 2
-    return min(lb, max_len)
+    lb = min(lb, max_len)
+    plan, spare = [], len(lens)
+    for i, n in enumerate(lens):
+        c = chunks(n, lb)
+        for j in range(c - 1):
+            plan.append((spare, i, j * lb, lb))
+            spare += 1
+        plan.append((i, i, (c - 1) * lb, n - (c - 1) * lb))
+    return lb, plan
 
 
 class ServingEngine:
@@ -531,6 +562,7 @@ class ServingEngine:
             "prefill_calls": 0,         # batched/fused prefill invocations
             "prefill_tokens": 0,        # prompt tokens prefill wrote
             "prefill_positions": 0,     # positions the prefill computed
+            "prefill_split_rows": 0,    # prefill rows holding an early chunk
             "decode_rows": 0,           # active rows over decode steps
             "decode_slots": 0,          # rows the decode steps computed
             "per_row_prefill_calls": 0,
@@ -870,13 +902,17 @@ class ServingEngine:
 
     def _prefill_batch(self, slots: list[int], reqs: list[Request],
                        effs: list[np.ndarray], starts: list[int]):
-        """Admit N requests with ONE forward: each row carries only its
+        """Admit N requests with ONE forward: each carries only its
         UNSHARED prompt suffix, right-padded to a shared bucket, written
         at positions ``start..len-1``. Ring mode blends the filled rows
-        into the slots' cache rows inside the jit; paged mode writes
-        straight into the slots' pages through their page tables (the
-        tables also expose the shared prefix pages, so suffix queries
-        attend across the whole prompt)."""
+        into the slots' cache rows inside the jit, one row per request.
+        Paged mode writes straight into the slots' pages through their
+        page tables (the tables also expose the shared prefix pages, so
+        suffix queries attend across the whole prompt), and spreads each
+        suffix over the rows no request holds (``_prefill_rows``): every
+        row writes its chunk's K/V into the pool before any row gathers
+        keys, so a chunk's queries see the earlier chunks' keys through
+        the same page table, as in one row."""
         lens = [len(e) - s for e, s in zip(effs, starts)]
         assert all(
             ln >= 1 for ln, s in zip(lens, starts) if s
@@ -884,8 +920,11 @@ class ServingEngine:
         assert (
             max(len(e) for e in effs) < self.max_len
         ), "admission rejects over-long prompts"
-        lb = _bucket_len(max(lens), self.max_len)
         nb = self.max_batch
+        paged = self.kv_mode == "paged"
+        lb, plan = _prefill_rows(lens, nb if paged else len(lens),
+                                 self.max_len)
+        split = len(plan) - len(lens)
         # rows write through their target slot's page table, truncated to
         # the admitted batch's used page columns (pow2-bucketed like the
         # decode table — prefill attention work then scales with the
@@ -899,7 +938,7 @@ class ServingEngine:
                    "keys": sum(n * s + n * (n + 1) // 2
                                for n, s in zip(lens, starts)),
                    "bucket": lb}
-            if self.kv_mode == "paged":
+            if paged:
                 out["width"] = width
             return out
 
@@ -908,15 +947,16 @@ class ServingEngine:
             lens_a = np.zeros(nb, np.int32)
             starts_a = np.zeros(nb, np.int32)
             valid = np.zeros(nb, bool)
-            for row, (eff, st) in enumerate(zip(effs, starts)):
-                tokens[row, :lens[row]] = eff[st:]
-                lens_a[row] = lens[row]
+            for row, i, off, n in plan:
+                st = starts[i] + off
+                tokens[row, :n] = effs[i][st:st + n]
+                lens_a[row] = n
                 starts_a[row] = st
                 valid[row] = True
-            if self.kv_mode == "paged":
+            if paged:
                 route = np.full((nb, width), -1, np.int32)
-                for row, slot in enumerate(slots):
-                    route[row] = self.page_table[slot, :width]
+                for row, i, _, _ in plan:
+                    route[row] = self.page_table[slots[i], :width]
                 args = (self.params, jnp.asarray(tokens),
                         jnp.asarray(lens_a), jnp.asarray(starts_a),
                         jnp.asarray(route), jnp.asarray(valid), self.cache)
@@ -935,6 +975,7 @@ class ServingEngine:
             self.stats["prefill_calls"] += 1
             self.stats["prefill_tokens"] += sum(lens)
             self.stats["prefill_positions"] += nb * lb
+            self.stats["prefill_split_rows"] += split
             tok0 = np.asarray(tok0)
         for row, (slot, req) in enumerate(zip(slots, reqs)):
             self._finish_admit(slot, req, effs[row], int(tok0[row]))

@@ -72,6 +72,25 @@ def test_work_counters_are_exact(serving):
     assert s["decode_steps"] == 3
     assert s["decode_rows"] == 2 + 1 + 1
     assert s["decode_slots"] == 3 * 2
+    assert s["prefill_split_rows"] == 0
+
+
+def test_work_counters_are_exact_when_a_prompt_splits(serving):
+    eng = serving.engine(max_batch=2, max_len=64, page_size=8,
+                         prefix_sharing=False)
+    eng.submit(Request(rid=0, prompt=np.arange(30, dtype=np.int32),
+                       max_tokens=3))
+    eng.run_to_completion()
+    s = eng.stats
+    # one 30-token prompt over both rows of a [2, 16] block, not one
+    # row of a [2, 32] block: its first 16 tokens on the idle row
+    assert s["prefill_calls"] == 1
+    assert s["prefill_tokens"] == 30
+    assert s["prefill_positions"] == 2 * 16
+    assert s["prefill_split_rows"] == 1
+    assert s["decode_steps"] == 2
+    assert s["decode_rows"] == 2
+    assert s["decode_slots"] == 2 * 2
 
 
 def test_t_prefill_orders_and_survives_preemption(serving):
@@ -120,8 +139,8 @@ def test_snapshot_exports_queue_wait_and_compiles(serving):
     snap = parse_prometheus(server.metrics_snapshot())
     assert snap["samd_process_compiles_total"] == tracing.compiles()
     assert snap["samd_request_queue_wait_seconds_count"] == 3
-    for name in ("prefill_tokens", "prefill_positions", "decode_rows",
-                 "decode_slots"):
+    for name in ("prefill_tokens", "prefill_positions",
+                 "prefill_split_rows", "decode_rows", "decode_slots"):
         assert snap[f"samd_engine_{name}_total"] == eng.stats[name]
     waits = [r.t_prefill - r.t_submit for r in server.finished]
     assert snap["samd_request_queue_wait_seconds_sum"] == pytest.approx(
